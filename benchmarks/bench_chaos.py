@@ -58,9 +58,9 @@ from repro.serve import (InjectedCrash, ServeEngine,              # noqa: E402
                          latest_checkpoint, list_checkpoints, synth_trace)
 from repro.serve.faults import FaultInjector, poison_requests     # noqa: E402
 from repro.serve.queue import COMPLETED, FAILED, TERMINAL         # noqa: E402
+from repro.launch.jaxcache import enable_compilation_cache        # noqa: E402
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,       # noqa: E402
-                     maybe_enable_jax_cache, maybe_enable_obs,
+from .common import (add_obs_args, emit, maybe_enable_obs,        # noqa: E402
                      platform_payload, write_obs)
 
 FAMILIES = ["lm", "tree", "lattice"]
@@ -347,10 +347,9 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="BENCH_chaos.json")
     ap.add_argument("--model-size", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     res = run(out=args.out, model_size=args.model_size, seed=args.seed)
     write_obs(args)

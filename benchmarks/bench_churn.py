@@ -42,10 +42,10 @@ from repro.core.executor import DynamicExecutor
 from repro.core.plan import BucketedPlanExecutor
 from repro.models.workloads import make_workload
 from repro.serve import ServeEngine, lm_request
+from repro.launch.jaxcache import enable_compilation_cache
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,
-                     maybe_enable_jax_cache, maybe_enable_obs,
-                     platform_payload, write_obs)
+from .common import (add_obs_args, emit, maybe_enable_obs, platform_payload,
+                     write_obs)
 
 # Prompt lengths deliberately straddle several scheduler buckets (4, 8, 16,
 # 32) and generation budgets vary, so the round-topology stream churns.
@@ -159,10 +159,9 @@ def main(argv=None) -> int:
     ap.add_argument("--max-slots", type=int, default=8)
     ap.add_argument("--skip-baselines", action="store_true",
                     help="run only the bucketed mode (fast smoke)")
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     modes = (("bucketed",) if args.skip_baselines
              else ("interpreted", "per_topology", "bucketed"))

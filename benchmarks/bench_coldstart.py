@@ -49,10 +49,10 @@ from repro.obs import Obs, Tracer
 from repro.serve import ServeEngine, synth_trace
 from repro.serve.faults import FaultInjector
 from repro.serve.queue import TERMINAL
+from repro.launch.jaxcache import enable_compilation_cache
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,
-                     maybe_enable_jax_cache, maybe_enable_obs,
-                     platform_payload, write_obs)
+from .common import (add_obs_args, emit, maybe_enable_obs, platform_payload,
+                     write_obs)
 
 DEADLINE = 500.0     # the gates measure cold-start latency, not SLO pressure
 
@@ -207,10 +207,9 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new", type=int, default=4)
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--seed", type=int, default=0)
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     res = run(out=args.out, model_size=args.model_size,
               requests=args.requests, max_new=args.max_new,

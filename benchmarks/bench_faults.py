@@ -38,10 +38,10 @@ from repro.models.workloads import SERVE_FAMILIES, make_workload
 from repro.serve import ServeEngine, synth_trace
 from repro.serve.faults import FaultInjector, poison_requests
 from repro.serve.queue import COMPLETED, FAILED, TERMINAL
+from repro.launch.jaxcache import enable_compilation_cache
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,
-                     maybe_enable_jax_cache, maybe_enable_obs,
-                     platform_payload, write_obs)
+from .common import (add_obs_args, emit, maybe_enable_obs, platform_payload,
+                     write_obs)
 
 FAMILIES = ["lm", "tree", "lattice"]
 
@@ -181,10 +181,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rate", type=float, default=2.0)
     ap.add_argument("--max-new", type=int, default=4)
     ap.add_argument("--max-slots", type=int, default=8)
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     res = run(out=args.out, model_size=args.model_size,
               requests=args.requests, rate=args.rate, max_new=args.max_new,

@@ -36,11 +36,11 @@ from repro.obs.tracer import validate_chrome_trace
 from repro.serve import ServeEngine, synth_trace
 from repro.serve.faults import FaultInjector, poison_requests
 from repro.serve.queue import FAILED, TIMED_OUT
+from repro.launch.jaxcache import enable_compilation_cache
 
 from .bench_churn import churn_trace
-from .common import (add_jax_cache_arg, add_obs_args, emit,
-                     maybe_enable_jax_cache, maybe_enable_obs,
-                     platform_payload, write_obs)
+from .common import (add_obs_args, emit, maybe_enable_obs, platform_payload,
+                     write_obs)
 from .fig8_decomposition import decompose_trace
 
 
@@ -222,10 +222,9 @@ def main(argv=None) -> int:
     ap.add_argument("--requests", type=int, default=10)
     ap.add_argument("--rate", type=float, default=2.0)
     ap.add_argument("--max-slots", type=int, default=8)
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     res = run(out=args.out, model_size=args.model_size,
               requests=args.requests, rate=args.rate,

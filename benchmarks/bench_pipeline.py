@@ -51,10 +51,10 @@ import time
 from repro.models.workloads import SERVE_FAMILIES, make_workload
 from repro.obs import Obs, Tracer
 from repro.serve import ServeEngine, synth_trace
+from repro.launch.jaxcache import enable_compilation_cache
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,
-                     maybe_enable_jax_cache, maybe_enable_obs,
-                     platform_payload, write_obs)
+from .common import (add_obs_args, emit, maybe_enable_obs, platform_payload,
+                     write_obs)
 from .fig8_decomposition import overlap_fraction, span_self_times
 
 
@@ -246,10 +246,9 @@ def main(argv=None) -> int:
     ap.add_argument("--reps", type=int, default=8)
     ap.add_argument("--max-slots", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     res = run(out=args.out, model_size=args.model_size,
               requests=args.requests, max_new=args.max_new,

@@ -18,10 +18,10 @@ from repro.core.batching import SufficientConditionPolicy
 from repro.core.executor import DynamicExecutor, ExecStats
 from repro.core.plan import PlanExecutor
 from repro.models.workloads import make_workload
+from repro.launch.jaxcache import enable_compilation_cache
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,
-                     maybe_enable_jax_cache, maybe_enable_obs,
-                     platform_payload, timeit, write_obs)
+from .common import (add_obs_args, emit, maybe_enable_obs, platform_payload,
+                     timeit, write_obs)
 
 
 def run(out: str = "", model_size: int = 64, batch_size: int = 16,
@@ -79,10 +79,9 @@ def main(argv=None) -> int:
     ap.add_argument("--batch-size", type=int, default=16)
     ap.add_argument("--no-donate", action="store_true",
                     help="disable arena donation (allocation per run)")
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     res = run(out=args.out, model_size=args.model_size,
               batch_size=args.batch_size, donate=not args.no_donate)

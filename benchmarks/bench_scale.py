@@ -48,9 +48,9 @@ from repro.core.cache import FIFOCache, LRUCache            # noqa: E402
 from repro.launch.mesh import make_data_mesh                # noqa: E402
 from repro.models.workloads import make_workload            # noqa: E402
 from repro.serve import ServeEngine, synth_trace            # noqa: E402
+from repro.launch.jaxcache import enable_compilation_cache        # noqa: E402
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,  # noqa: E402
-                     maybe_enable_jax_cache, maybe_enable_obs,
+from .common import (add_obs_args, emit, maybe_enable_obs,        # noqa: E402
                      platform_payload, write_obs)
 
 FAMILY_MIX = ["lm", "lm", "lm", "tree", "lattice"]
@@ -152,10 +152,9 @@ def main(argv=None) -> int:
                     help="comma-separated replica counts to measure")
     ap.add_argument("--arrivals", choices=["constant", "poisson", "burst"],
                     default="constant")
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     replicas = tuple(int(x) for x in args.replicas.split(",") if x.strip())
     res = run(out=args.out, model_size=args.model_size,

@@ -31,10 +31,10 @@ import numpy as np
 from repro.core.cache import FIFOCache, LRUCache
 from repro.models.workloads import make_workload
 from repro.serve import ServeEngine, synth_trace
+from repro.launch.jaxcache import enable_compilation_cache
 
-from .common import (add_jax_cache_arg, add_obs_args, emit,
-                     maybe_enable_jax_cache, maybe_enable_obs,
-                     platform_payload, write_obs)
+from .common import (add_obs_args, emit, maybe_enable_obs, platform_payload,
+                     write_obs)
 
 
 def lm_trace(workloads, n, rate, max_new, seed=0):
@@ -127,10 +127,9 @@ def main(argv=None) -> int:
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=20)
     ap.add_argument("--rate", type=float, default=4.0)
-    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
-    maybe_enable_jax_cache(args)
+    enable_compilation_cache()
     maybe_enable_obs(args)
     res = run(out=args.out, model_size=args.model_size,
               requests=args.requests, max_new=args.max_new, rate=args.rate)

@@ -8,22 +8,8 @@ import time
 
 from repro.obs.metrics import default_registry, latency_summary, percentile
 
-__all__ = ["add_jax_cache_arg", "maybe_enable_jax_cache", "add_obs_args",
-           "maybe_enable_obs", "write_obs", "platform_payload", "timeit",
-           "emit", "make_executor", "percentile", "latency_summary"]
-
-
-def add_jax_cache_arg(ap) -> None:
-    """`--jax-cache DIR`: persistent XLA compilation cache, so residual
-    per-bucket/per-topology compiles survive process restarts."""
-    ap.add_argument("--jax-cache", default="",
-                    help="persistent XLA compilation cache dir")
-
-
-def maybe_enable_jax_cache(args) -> None:
-    if getattr(args, "jax_cache", ""):
-        from repro.launch.jaxcache import enable_compilation_cache
-        enable_compilation_cache(args.jax_cache)
+__all__ = ["add_obs_args", "maybe_enable_obs", "write_obs", "platform_payload",
+           "timeit", "emit", "make_executor", "percentile", "latency_summary"]
 
 
 def add_obs_args(ap) -> None:

@@ -28,6 +28,7 @@ from repro.obs.tracer import Tracer, default_tracer
 from .batching import (Policy, Schedule, policy_cache_key, resolve_schedule)
 from .cache import FIFOCache
 from .graph import Graph, TypeId
+from .ops import MATMUL_PRECISION
 
 
 class NodeImpl:
@@ -256,5 +257,6 @@ def embed_impl(name: str, table: jnp.ndarray, field_name: str = "h") -> NodeImpl
 def affine_impl(name: str, w: jnp.ndarray, b: jnp.ndarray,
                 in_field: str = "h", out_field: str = "h") -> NodeImpl:
     def apply(params, inputs, aux):
-        return {out_field: inputs[0] @ w + b}
+        return {out_field: jnp.matmul(inputs[0], w,
+                                      precision=MATMUL_PRECISION) + b}
     return NodeImpl(name, [(0, in_field)], {out_field: (w.shape[1],)}, apply)

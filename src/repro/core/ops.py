@@ -12,7 +12,15 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Sequence
 
+import jax
 import jax.numpy as jnp
+
+# Every f32 matmul of a served model runs at this one precision, whatever
+# the tier: XLA fusions in compiled plans, Mosaic in the fused Pallas cell,
+# and eager ops on the interpreted floor. Left at DEFAULT, a TPU runs the
+# three at different precisions, and containment re-runs, the coarse bridge
+# and speculation all assume every tier yields the same argmax tokens.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @dataclass(frozen=True)
@@ -37,20 +45,21 @@ def _affine(x, w, b):
     x = _bk(x, 1)
     w = _bk(w, 2)
     b = _bk(b, 1)
-    return jnp.einsum("bkn,cknm->bkm", x, w) + b
+    return jnp.einsum("bkn,cknm->bkm", x, w,
+                      precision=MATMUL_PRECISION) + b
 
 
 def _matmul(x, w):
     x = _bk(x, 1)
     w = _bk(w, 2)
-    return jnp.einsum("bkn,cknm->bkm", x, w)
+    return jnp.einsum("bkn,cknm->bkm", x, w, precision=MATMUL_PRECISION)
 
 
 def _matmul_vv(a, b):
     # MV-RNN style: (B,k,n,n) x (B,k,n) matrices applied to vectors
     a = _bk(a, 2)
     b = _bk(b, 1)
-    return jnp.einsum("bknm,bkm->bkn", a, b)
+    return jnp.einsum("bknm,bkm->bkn", a, b, precision=MATMUL_PRECISION)
 
 
 def _ew(f):
@@ -95,7 +104,8 @@ def _matmat(a, b):
     # (k,n,m) or (B,k,n,m) times (B,k,m,p)
     a = _bk(a, 2)
     b = _bk(b, 2)
-    return jnp.einsum("cknm,bkmp->bknp", a, b)
+    return jnp.einsum("cknm,bkmp->bknp", a, b,
+                      precision=MATMUL_PRECISION)
 
 
 _register("matmat", 2, lambda a, b: (a[-2], b[-1]), _matmat)

@@ -63,7 +63,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.obs.tracer import Tracer, default_tracer
@@ -1305,9 +1304,14 @@ class ShardedBucketedPlanExecutor(BucketedPlanExecutor):
                 out = prog.body(p, idx[0], aux[0], sq(pools))
                 return jax.tree.map(lambda x: x[None], out)
 
-            fn = shard_map(one_shard, mesh=self.mesh,
-                           in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
-                           out_specs=P(axis))
+            # check_vma off: the per-shard body has no collectives, so
+            # there is nothing for the varying-axes check to verify, and
+            # the Pallas calls inside would otherwise have to declare the
+            # vma of every output shape.
+            fn = jax.shard_map(one_shard, mesh=self.mesh,
+                               in_specs=(P(), P(axis), P(axis), P(axis),
+                                         P(axis)),
+                               out_specs=P(axis), check_vma=False)
             K = self.n_shards
             idx_spec = jax.ShapeDtypeStruct((K, sspec.n_index_lanes),
                                             jnp.int32)
@@ -1339,8 +1343,12 @@ class ShardedBucketedPlanExecutor(BucketedPlanExecutor):
             if g is None:
                 results.append(None)
                 continue
+            # Host copies: a slice of a mesh-sharded leaf is replicated
+            # over the mesh, and the single-device program given one would
+            # be partitioned across it (Mosaic kernels refuse that).
             mine = (None if shard_params is None
-                    else jax.tree.map(lambda x: x[s], shard_params))
+                    else jax.tree.map(lambda x: np.asarray(x[s]),
+                                      shard_params))
             results.append(super().run(g, policy, stats,
                                        params=_merge_params(params, mine)))
         return results

@@ -16,6 +16,11 @@ Weight layout matches ``fused_cell``: ``w`` is ``(E+H, 4H)`` with gate
 columns blocked ``[i|f|g|o]``; ``b`` is ``(4H,)``. The dispatching wrapper
 falls back to a pure-jnp gather+cell (which XLA fuses on its own) off-TPU
 or for lane-misaligned hidden sizes.
+
+Row operands move through free ``(N, 1, D)`` views with the row axis
+squeezed out of the block (see ``gather_batch.gather_rows_kernel``: Mosaic
+refuses a ``(1, D)`` block over ``(N, D)``). The gate matmul runs at
+:data:`repro.core.ops.MATMUL_PRECISION`, the precision of every other tier.
 """
 
 from __future__ import annotations
@@ -27,12 +32,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.ops import MATMUL_PRECISION
+
 
 def _kernel(ix_ref, ih_ref, ic_ref, x_ref, h_ref, c_ref, w_ref, b_ref,
             h_out_ref, c_out_ref, *, hidden: int):
     # index_maps already routed this program's gathered rows here.
     xh = jnp.concatenate([x_ref[...], h_ref[...]], axis=-1)   # (1, E+H)
     y = jax.lax.dot_general(xh, w_ref[...], (((1,), (0,)), ((), ())),
+                            precision=MATMUL_PRECISION,
                             preferred_element_type=jnp.float32)
     y = y + b_ref[...].astype(jnp.float32)                    # (1, 4H)
     i = jax.nn.sigmoid(y[:, 0 * hidden:1 * hidden])
@@ -53,30 +61,30 @@ def fused_gather_lstm_cell_kernel(x_src, h_src, c_src, ix, ih, ic, w, b, *,
     E = x_src.shape[1]
     H = h_src.shape[1]
     kernel = functools.partial(_kernel, hidden=H)
+
+    out_row = pl.BlockSpec((None, 1, H), lambda i, *_: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, E), lambda i, ix_ref, ih_ref, ic_ref: (ix_ref[i], 0)),
-            pl.BlockSpec((1, H), lambda i, ix_ref, ih_ref, ic_ref: (ih_ref[i], 0)),
-            pl.BlockSpec((1, H), lambda i, ix_ref, ih_ref, ic_ref: (ic_ref[i], 0)),
+            pl.BlockSpec((None, 1, E), lambda i, ix, ih, ic: (ix[i], 0, 0)),
+            pl.BlockSpec((None, 1, H), lambda i, ix, ih, ic: (ih[i], 0, 0)),
+            pl.BlockSpec((None, 1, H), lambda i, ix, ih, ic: (ic[i], 0, 0)),
             pl.BlockSpec((E + H, 4 * H), lambda i, *_: (0, 0)),
             pl.BlockSpec((1, 4 * H), lambda i, *_: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, H), lambda i, *_: (i, 0)),
-            pl.BlockSpec((1, H), lambda i, *_: (i, 0)),
-        ],
+        out_specs=[out_row, out_row],
     )
     h_out, c_out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H), h_src.dtype),
-                   jax.ShapeDtypeStruct((B, H), h_src.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, H), h_src.dtype),
+                   jax.ShapeDtypeStruct((B, 1, H), h_src.dtype)],
         interpret=interpret,
     )(ix.astype(jnp.int32), ih.astype(jnp.int32), ic.astype(jnp.int32),
-      x_src, h_src, c_src, w, b.reshape(1, 4 * H))
-    return h_out, c_out
+      x_src.reshape(-1, 1, E), h_src.reshape(-1, 1, H),
+      c_src.reshape(-1, 1, H), w, b.reshape(1, 4 * H))
+    return h_out.reshape(B, H), c_out.reshape(B, H)
 
 
 def _jnp_fallback(x_src, h_src, c_src, ix, ih, ic, w, b):
@@ -85,7 +93,7 @@ def _jnp_fallback(x_src, h_src, c_src, ix, ih, ic, w, b):
     xh = jnp.concatenate([jnp.take(x_src, ix, axis=0),
                           jnp.take(h_src, ih, axis=0)], axis=-1)
     H = h_src.shape[1]
-    y = xh @ w + b
+    y = jnp.matmul(xh, w, precision=MATMUL_PRECISION) + b
     i = jax.nn.sigmoid(y[:, 0 * H:1 * H])
     f = jax.nn.sigmoid(y[:, 1 * H:2 * H])
     g = jnp.tanh(y[:, 2 * H:3 * H])

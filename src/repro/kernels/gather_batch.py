@@ -25,7 +25,12 @@ def _gather_kernel(idx_ref, src_ref, out_ref):
 
 def gather_rows_kernel(src, idx, *, block_d: int = 512,
                        interpret: bool = False):
-    """src: (N, D); idx: (K,) int32 -> (K, D) == src[idx]."""
+    """src: (N, D); idx: (K,) int32 -> (K, D) == src[idx].
+
+    Rows move through a free ``(N, 1, D)`` view with the row axis squeezed
+    out of the block: Mosaic requires a block's last two dims to be
+    (8, 128)-divisible or equal to the array's, and a ``(1, bd)`` block
+    over ``(N, D)`` is neither, while ``(1, bd)`` over ``(N, 1, D)`` is."""
     N, D = src.shape
     K = idx.shape[0]
     bd = min(block_d, D)
@@ -36,16 +41,19 @@ def gather_rows_kernel(src, idx, *, block_d: int = 512,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bd), lambda i, j, idx_ref: (idx_ref[i], j)),
+            pl.BlockSpec((None, 1, bd),
+                         lambda i, j, idx_ref: (idx_ref[i], 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda i, j, idx_ref: (i, j)),
+        out_specs=pl.BlockSpec((None, 1, bd),
+                               lambda i, j, idx_ref: (i, 0, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, D), src.dtype),
+        out_shape=jax.ShapeDtypeStruct((K, 1, D), src.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), src)
+    )(idx.astype(jnp.int32), src.reshape(N, 1, D))
+    return out.reshape(K, D)
 
 
 def gather_rows(src, idx, *, interpret: bool = False):

@@ -2,12 +2,17 @@
 
 Bucketed plan compilation shrinks the number of distinct XLA compiles to
 one per bucket signature — but each of those still recurs on every process
-restart. JAX's persistent compilation cache
-(``jax_compilation_cache_dir``) keeps the compiled executables on disk, so
-a restarted server or a re-run benchmark pays a cache *read* instead of a
-compile. Off by default (it writes to disk and its key includes the
-jaxlib build), enabled behind ``--jax-cache DIR`` in ``launch/serve.py``
-and the benchmarks.
+restart. JAX's persistent compilation cache keeps the compiled executables
+on disk, so a restarted server or a re-run benchmark pays a cache *read*
+instead of a compile.
+
+Where the cache lives: ``$JAX_COMPILATION_CACHE_DIR`` when it is set (JAX
+reads it itself; no other directory is set in code), otherwise one fixed
+path inside the checkout, ``<checkout>/.jax_cache``. The path is part of
+the cache's key, so it never moves: no temp, pid or time-based directory.
+Entry points (``launch/serve.py``, ``chip_smoke.py``, the benchmarks) call
+:func:`enable_compilation_cache` before their first compile; importing a
+module never turns the cache on.
 
 The same directory also hosts ``warmset.json`` — the speculative
 warm-start record for the async compile service (DESIGN.md §8): the
@@ -24,6 +29,19 @@ import warnings
 
 QUARANTINE_SUBDIR = "_quarantine"
 WARMSET_NAME = "warmset.json"
+# JAX's executable entries; the directory also holds JAX's zero-byte
+# ``.lockfile`` and ``-atime`` stamps, which are not entries.
+ENTRY_SUFFIX = "-cache"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/launch/jaxcache.py -> <checkout>/.jax_cache
+DEFAULT_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
+
+
+def cache_dir() -> str:
+    """The persistent cache directory: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else the fixed ``<checkout>/.jax_cache``."""
+    return os.environ.get(CACHE_ENV) or DEFAULT_CACHE_DIR
 
 
 def warmset_path(cache_dir: str) -> str:
@@ -73,9 +91,10 @@ def save_warmset(cache_dir: str, warmset: dict) -> str | None:
 
 def audit_cache_dir(cache_dir: str) -> list[str]:
     """Sweep a persistent cache directory for corrupt entries before JAX
-    reads them: zero-byte or unreadable files (the residue of a crash or a
-    full disk mid-write) are moved into a ``_quarantine/`` subdirectory —
-    the entry recompiles fresh instead of poisoning the serve launcher.
+    reads them: zero-byte or unreadable ``*-cache`` entries (the residue of
+    a crash or a full disk mid-write) are moved into a ``_quarantine/``
+    subdirectory — the entry recompiles fresh instead of poisoning the
+    serve launcher.
     Returns the quarantined paths (empty on a healthy dir)."""
     quarantined: list[str] = []
     try:
@@ -85,7 +104,7 @@ def audit_cache_dir(cache_dir: str) -> list[str]:
     qdir = os.path.join(cache_dir, QUARANTINE_SUBDIR)
     for name in names:
         path = os.path.join(cache_dir, name)
-        if not os.path.isfile(path):
+        if not name.endswith(ENTRY_SUFFIX) or not os.path.isfile(path):
             continue
         bad = None
         try:
@@ -113,36 +132,31 @@ def audit_cache_dir(cache_dir: str) -> list[str]:
     return quarantined
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+def enable_compilation_cache() -> bool:
+    """Turn on JAX's persistent compilation cache at :func:`cache_dir`.
 
+    Call before the process's first compile: JAX decides once whether the
+    cache is in use. With ``$JAX_COMPILATION_CACHE_DIR`` set, JAX has
+    already read the directory and only the size gates are set here.
     The min-compile-time/min-entry-size gates are zeroed so even the toy
     CI-sized programs are cached — the whole point here is surviving
-    process restarts, not saving disk. Returns False (with a warning)
-    when the running jax build lacks the config knobs. A pre-existing dir
-    is audited first: corrupt/truncated entries are quarantined so the
-    launcher falls through to a fresh compile instead of crashing.
+    process restarts, not saving disk. A pre-existing dir is audited
+    first: corrupt/truncated entries are quarantined so the launcher falls
+    through to a fresh compile instead of crashing. Returns False (with a
+    warning) when the path is not a usable directory.
     """
-    if not cache_dir:
-        return False
-    if os.path.exists(cache_dir) and not os.path.isdir(cache_dir):
+    path = cache_dir()
+    if os.path.exists(path) and not os.path.isdir(path):
         warnings.warn(
-            f"persistent compilation cache path {cache_dir!r} exists but is "
+            f"persistent compilation cache path {path!r} exists but is "
             f"not a directory; continuing without the cache",
             RuntimeWarning, stacklevel=2)
         return False
-    audit_cache_dir(cache_dir)
+    audit_cache_dir(path)
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except AttributeError:
-            pass   # older knob names; directory alone still caches big entries
-        return True
-    except Exception as e:   # noqa: BLE001 — cache is a best-effort speedup
-        warnings.warn(f"persistent compilation cache unavailable: {e!r}",
-                      RuntimeWarning, stacklevel=2)
-        return False
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return True

@@ -42,6 +42,7 @@ from repro.obs.metrics import default_registry
 from repro.obs.tracer import default_tracer
 from repro.serve import (PolicyRegistry, ServeEngine, graph_request,
                          lm_request, synth_trace)
+from repro.serve.compiler import DEFAULT_TIMEOUT_S as DEFAULT_COMPILE_TIMEOUT_S
 
 
 def load_trace(path: str, workloads, max_new_default: int):
@@ -145,9 +146,6 @@ def main(argv=None):
                     help="bucketed: one XLA executable per bucket signature "
                          "(topology churn = host-side repack); compiled: one "
                          "per topology; interpreted: reference executor")
-    ap.add_argument("--jax-cache", default="",
-                    help="persistent XLA compilation cache dir (residual "
-                         "per-bucket compiles survive process restarts)")
     ap.add_argument("--no-pipeline", action="store_true",
                     help="disable round pipelining (DESIGN.md §9): run "
                          "pack/dispatch/block serially each round instead "
@@ -162,15 +160,16 @@ def main(argv=None):
     ap.add_argument("--compile-workers", type=int, default=2,
                     help="background compile worker threads (async "
                          "compile only)")
-    ap.add_argument("--compile-timeout", type=float, default=30.0,
+    ap.add_argument("--compile-timeout", type=float,
+                    default=DEFAULT_COMPILE_TIMEOUT_S,
                     help="per-compile-job wall-clock timeout in seconds; a "
                          "job past it is abandoned and retried with "
                          "backoff, then quarantined")
     ap.add_argument("--warm-start", action="store_true",
                     help="pre-submit compile jobs for the bucket "
-                         "signatures recorded in the warmset next to "
-                         "--jax-cache, and record this run's signatures "
-                         "back (async compile only; needs --jax-cache)")
+                         "signatures recorded in the warmset next to the "
+                         "persistent compilation cache, and record this "
+                         "run's signatures back (async compile only)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--deadline-ms", type=float, default=0.0,
                     help="per-request SLO in virtual ms (1 scheduler round "
@@ -245,9 +244,6 @@ def main(argv=None):
     if args.warm_start and not use_async:
         ap.error("--warm-start needs async compile "
                  "(--plan bucketed without --no-async-compile)")
-    if args.warm_start and not args.jax_cache:
-        print("# --warm-start without --jax-cache: nothing persisted from "
-              "a prior run; continuing cold")
     if args.devices > 1:
         import jax
         n = len(jax.devices())
@@ -257,9 +253,8 @@ def main(argv=None):
                      f"--xla_force_host_platform_device_count="
                      f"{args.devices}")
 
-    if args.jax_cache:
-        from repro.launch.jaxcache import enable_compilation_cache
-        enable_compilation_cache(args.jax_cache)
+    from repro.launch.jaxcache import cache_dir, enable_compilation_cache
+    enable_compilation_cache()
 
     if args.legacy_arch:
         return legacy_wave(args.legacy_arch, args.requests, args.max_new,
@@ -359,12 +354,12 @@ def main(argv=None):
                           pipeline=not args.no_pipeline)
         eng.submit_many(reqs)
 
-    if args.warm_start and args.jax_cache:
+    if args.warm_start:
         from repro.launch.jaxcache import load_warmset
-        n_warm = eng.prewarm(load_warmset(args.jax_cache))
+        n_warm = eng.prewarm(load_warmset(cache_dir()))
         if n_warm:
             print(f"# warm-start: pre-submitted {n_warm} compile job(s) "
-                  f"from {args.jax_cache}")
+                  f"from {cache_dir()}")
 
     import time as _time
     t_serve0 = _time.perf_counter()
@@ -435,10 +430,10 @@ def main(argv=None):
               f"lower {stats.lower_s:.2f}s on-loop / "
               f"{stats.lower_bg_s:.2f}s background; "
               f"cold-start ttft {ttft}")
-    if args.warm_start and args.jax_cache:
+    if args.warm_start:
         from repro.launch.jaxcache import save_warmset
-        if save_warmset(args.jax_cache, eng.warmset()):
-            print(f"# warmset saved next to {args.jax_cache}")
+        if save_warmset(cache_dir(), eng.warmset()):
+            print(f"# warmset saved next to {cache_dir()}")
     eng.close()
     if registry is not None and registry.diagnostics:
         for fam, bad in sorted(registry.diagnostics.items()):

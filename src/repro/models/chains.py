@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core.executor import NodeImpl, cell_impl, embed_impl
 from repro.core.graph import Graph, Node
+from repro.core.ops import MATMUL_PRECISION
 from repro.core.subgraph import CompiledCell
 from .cells import lstm_cell
 from .data import random_sentence
@@ -46,7 +47,8 @@ class BiLSTMTagger:
         bo = jnp.zeros(N_TAGS, jnp.float32)
 
         def out_apply(params, inputs, aux):
-            return {"y": jnp.concatenate(inputs, axis=-1) @ wo + bo}
+            return {"y": jnp.matmul(jnp.concatenate(inputs, axis=-1), wo,
+                                    precision=MATMUL_PRECISION) + bo}
 
         self.impls = {
             "E": embed_impl("E", table, "x"),
@@ -128,7 +130,8 @@ class ChainLM:
         bo = jnp.zeros(vocab, jnp.float32)
 
         def out_apply(params, inputs, aux):
-            return {"y": inputs[0] @ wo + bo}
+            return {"y": jnp.matmul(inputs[0], wo,
+                                    precision=MATMUL_PRECISION) + bo}
 
         def slot_apply(params, inputs, aux):
             slots = params["slots"]       # engine-threaded, (max_slots, h)
@@ -185,7 +188,8 @@ class LSTMNMT:
         bo = jnp.zeros(OUT_VOCAB, jnp.float32)
 
         def out_apply(params, inputs, aux):
-            return {"y": inputs[0] @ wo + bo}
+            return {"y": jnp.matmul(inputs[0], wo,
+                                    precision=MATMUL_PRECISION) + bo}
 
         self.impls = {
             "Es": embed_impl("Es", src_table, "x"),

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core.executor import NodeImpl, cell_impl, embed_impl
 from repro.core.graph import Graph, Node
+from repro.core.ops import MATMUL_PRECISION
 from repro.core.subgraph import CompiledCell
 from .cells import gru_cell, lattice_char_gru, lattice_char_lstm, lstm_cell
 from .data import random_lattice
@@ -41,7 +42,7 @@ class LatticeLSTM:
         wo = jnp.asarray(0.1 * rng.standard_normal((h, N_TAGS)), jnp.float32)
 
         def out_apply(params, inputs, aux):
-            return {"y": inputs[0] @ wo}
+            return {"y": jnp.matmul(inputs[0], wo, precision=MATMUL_PRECISION)}
 
         def zero_apply(params, inputs, aux):
             z = jnp.zeros((aux.shape[0], h), jnp.float32)
@@ -113,7 +114,7 @@ class LatticeGRU:
         wo = jnp.asarray(0.1 * rng.standard_normal((h, N_TAGS)), jnp.float32)
 
         def out_apply(params, inputs, aux):
-            return {"y": inputs[0] @ wo}
+            return {"y": jnp.matmul(inputs[0], wo, precision=MATMUL_PRECISION)}
 
         def zero_apply(params, inputs, aux):
             return {"h_out": jnp.zeros((aux.shape[0], h), jnp.float32)}

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core.executor import NodeImpl, cell_impl, embed_impl
 from repro.core.graph import Graph, Node
+from repro.core.ops import MATMUL_PRECISION
 from repro.core.subgraph import CompiledCell
 from .cells import (gru_cell, mv_cell, treegru_internal, treegru_leaf,
                     treelstm_internal, treelstm_leaf)
@@ -54,7 +55,7 @@ def _out_impl(rng: np.random.Generator, hidden: int) -> NodeImpl:
     b = jnp.zeros(N_CLASSES, jnp.float32)
 
     def apply(params, inputs, aux):
-        return {"y": inputs[0] @ w + b}
+        return {"y": jnp.matmul(inputs[0], w, precision=MATMUL_PRECISION) + b}
 
     return NodeImpl("O", [(0, "h")], {"y": (N_CLASSES,)}, apply)
 

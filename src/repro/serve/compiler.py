@@ -51,6 +51,14 @@ RUNNING = "running"          # a worker is building it
 LANDED = "landed"            # executable in the shared cache
 QUARANTINED = "quarantined"  # retry budget exhausted; signature quarantined
 
+# Per-job wall-clock timeout. On a TPU v5e the slowest bucket compile of
+# the model_size-512 lm/tree/lattice smoke trace took 7.8 s with two
+# workers compiling at once (chip_smoke.py). Compile time grows with the
+# steps a program unrolls, and a job past the timeout is retried and then
+# quarantined, its rounds served by the interpreted floor for good; the
+# timeout exists only to catch a wedged build, so it sits ~15x above that.
+DEFAULT_TIMEOUT_S = 120.0
+
 _STAT_KEYS = ("submitted", "landed", "retries", "timeouts", "failures",
               "quarantined", "late_lands")
 
@@ -101,7 +109,8 @@ class CompileService:
     call so the engine can account hot-swaps.
     """
 
-    def __init__(self, workers: int = 2, timeout_s: float = 30.0,
+    def __init__(self, workers: int = 2,
+                 timeout_s: float = DEFAULT_TIMEOUT_S,
                  max_retries: int = 2, retry_backoff_s: float = 0.1,
                  quarantine: Any = None, metrics: Any = None,
                  on_quarantine: Callable[[CompileJob], None] | None = None):

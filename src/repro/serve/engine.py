@@ -70,6 +70,7 @@ from repro.models.workloads import SERVE_FAMILIES, make_workload
 from repro.obs import FlightRecorder, Obs, Tracer
 from repro.obs.metrics import percentile
 
+from .compiler import DEFAULT_TIMEOUT_S as DEFAULT_COMPILE_TIMEOUT_S
 from .faults import (BAD_TOPOLOGY, DEADLINE_EXCEEDED, EXEC_ERROR,
                      ROUND_BUDGET_EXCEEDED, InjectedCrash, Quarantine,
                      validate_request)
@@ -298,7 +299,7 @@ class ServeEngine:
                  steal_threshold: int | None = None,
                  async_compile: bool = False,
                  compile_workers: int = 2,
-                 compile_timeout_s: float = 30.0,
+                 compile_timeout_s: float = DEFAULT_COMPILE_TIMEOUT_S,
                  pipeline: bool = True):
         self.compiled = compiled
         self.bucketed = bucketed
@@ -1772,7 +1773,11 @@ class ServeEngine:
                 continue
             st = self._shard_stats[s]
             try:
-                mine = {"slots": {f: pool[f][s] for f in pool}}
+                # Host copies of the shard's rows: a slice of the
+                # mesh-sharded pool is an array replicated over the mesh,
+                # and a single-device program given one is partitioned
+                # across it, which Mosaic refuses for the Pallas kernels.
+                mine = {"slots": {f: np.asarray(pool[f][s]) for f in pool}}
                 res = ex.run(g, pol, es, params=mine)
             except Exception as exc:
                 self._contained()
@@ -1786,7 +1791,7 @@ class ServeEngine:
             shards = np.full(len(entries), s, np.int32)
             for f in wl.state_fields:
                 pool[f] = pool[f].at[shards, slots].set(
-                    jnp.asarray(res.field(f, cell_ids)))
+                    np.asarray(res.field(f, cell_ids)))
             self._feed_tokens(entries, np.argmax(ys, axis=-1), now, st)
 
     def _run_single_shot(self, fam: str, reqs: list[ServeRequest]) -> None:

@@ -42,6 +42,7 @@ import numpy as np
 
 from .checkpoint import (CheckpointError, decode_array, decode_request,
                          encode_array, encode_request, read_checkpoint)
+from .compiler import DEFAULT_TIMEOUT_S
 from .engine import ServeEngine, ServeStats
 from .queue import reserve_rids
 
@@ -224,7 +225,8 @@ def restore_engine(source, families: dict[str, Any] | None = None, *,
         compile_workers=(compile_workers if compile_workers is not None
                          else cfg.get("compile_workers", 2)),
         compile_timeout_s=(compile_timeout_s if compile_timeout_s is not None
-                           else cfg.get("compile_timeout_s", 30.0)),
+                           else cfg.get("compile_timeout_s",
+                                        DEFAULT_TIMEOUT_S)),
         pipeline=cfg.get("pipeline", True))
     with eng.tracer.span("ckpt.restore", round=payload["clock"]["round"],
                          reason=payload.get("reason", "")):

@@ -80,3 +80,17 @@ def test_serve_engine_batches_requests():
     # 2 prompt-length types + 3 decode waves
     assert stats.n_prefill_batches == 2
     assert stats.n_decode_batches == 3
+
+
+def test_chip_smoke_refuses_a_non_tpu_backend():
+    """chip_smoke.py's device guard: no result off a TPU (here, the CPU)."""
+    import importlib.util
+
+    import jax
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    with pytest.raises(SystemExit, match="needs a TPU"):
+        chip_smoke.require_tpu(jax.devices())

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.launch.jaxcache import (QUARANTINE_SUBDIR, audit_cache_dir,
-                                   enable_compilation_cache)
+                                   cache_dir, enable_compilation_cache)
 from repro.models.workloads import make_workload
 from repro.obs import FlightRecorder, MetricsRegistry, Obs, Tracer
 from repro.serve import (InjectedCrash, ServeEngine, graph_request,
@@ -283,23 +283,37 @@ def test_restore_mismatch_dumps_flight_recorder(workloads, tmp_path):
 
 
 def test_audit_cache_dir_quarantines_corrupt_entries(tmp_path):
-    good = tmp_path / "entry_good"
+    good = tmp_path / "entry_good-cache"
     good.write_bytes(b"xla!")
-    (tmp_path / "entry_torn").write_bytes(b"")   # zero-byte: crash residue
+    (tmp_path / "entry_torn-cache").write_bytes(b"")   # crash residue
+    lock = tmp_path / ".lockfile"       # JAX's own, zero bytes by design
+    lock.write_bytes(b"")
     with pytest.warns(RuntimeWarning, match="quarantined corrupt"):
         moved = audit_cache_dir(str(tmp_path))
     assert len(moved) == 1 and QUARANTINE_SUBDIR in moved[0]
-    assert good.exists()
-    assert not (tmp_path / "entry_torn").exists()
-    assert (tmp_path / QUARANTINE_SUBDIR / "entry_torn").exists()
+    assert good.exists() and lock.exists()
+    assert not (tmp_path / "entry_torn-cache").exists()
+    assert (tmp_path / QUARANTINE_SUBDIR / "entry_torn-cache").exists()
     assert audit_cache_dir(str(tmp_path / "missing")) == []
 
 
-def test_enable_cache_refuses_non_directory(tmp_path):
+def test_enable_cache_refuses_non_directory(tmp_path, monkeypatch):
     f = tmp_path / "not_a_dir"
     f.write_text("x")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(f))
     with pytest.warns(RuntimeWarning, match="not a directory"):
-        assert enable_compilation_cache(str(f)) is False
+        assert enable_compilation_cache() is False
+
+
+def test_cache_dir_env_wins_else_fixed_checkout_path(tmp_path, monkeypatch):
+    import os
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cache_dir() == os.path.join(root, ".jax_cache")
+    assert cache_dir() == cache_dir()   # never a per-call temp name
 
 
 # -- elastic mesh resize (multi-device) ---------------------------------------
