@@ -30,11 +30,15 @@ from .common import emit, make_executor
 # container spans (serve.run / serve.round / round.lm / round.single) is
 # engine overhead and lands in "other".
 COMPONENTS = {
-    "schedule": ("round.schedule", "plan.schedule", "interp.schedule"),
+    "schedule": ("round.schedule", "plan.schedule", "interp.schedule",
+                 "round.lookup", "round.speculate", "round.spec_check",
+                 "round.spec_snapshot"),
     "memory": ("round.pack", "plan.pack", "plan.lower", "plan.h2d",
-               "round.scatter", "round.feed", "round.feed_stage"),
-    "execution": ("plan.dispatch", "plan.block", "interp.exec"),
-    "compile": ("xla.compile",),
+               "round.scatter", "round.commit", "round.readback",
+               "round.feed", "round.feed_stage"),
+    "execution": ("round.dispatch", "plan.dispatch", "plan.block",
+                  "interp.exec"),
+    "compile": ("xla.compile", "jax.trace", "jax.compile"),
 }
 
 

@@ -1102,12 +1102,15 @@ class BucketedPlanExecutor:
             # and folds the transfer into the dispatch call, instead of
             # paying a separate eager device-put dispatch per round.
             aux = _node_aux_np(graph, pack.aux_perm)
-        key, entry, compile_s = self._ensure_executable(pack, params)
-        exe, pool, impls_pin = entry
-        t1 = time.perf_counter()
         with tr.span("plan.dispatch", cat="plan"):
+            # The executable's lookup (a build, on the tiers that compile
+            # on the loop, nests its own xla.compile span), then the call
+            # that hands the program to the device.
+            key, entry, compile_s = self._ensure_executable(pack, params)
+            exe, pool, impls_pin = entry
+            t1 = time.perf_counter()
             arenas = exe(params, pack.idxpack, aux, pool)
-        dispatch_s = time.perf_counter() - t1
+            dispatch_s = time.perf_counter() - t1
         return InFlightDispatch(self, graph, pack, key, exe, arenas,
                                 impls_pin, stats, dispatch_s, compile_s)
 

@@ -30,6 +30,7 @@ qwen2-0.5b`` serves one wave through it for comparison).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import random
 
@@ -37,7 +38,7 @@ import numpy as np
 
 from repro.core.rl import RLConfig, train_fsm
 from repro.models.workloads import SERVE_FAMILIES, make_workload
-from repro.obs import FlightRecorder, Obs
+from repro.obs import FlightRecorder, Obs, Tracer
 from repro.obs.metrics import default_registry
 from repro.obs.tracer import default_tracer
 from repro.serve import (PolicyRegistry, ServeEngine, graph_request,
@@ -114,6 +115,23 @@ def legacy_wave(arch: str, requests: int, max_new: int, seed: int,
           f"in {stats.wall_s:.2f}s ({stats.tok_per_s:.1f} tok/s), "
           f"{stats.n_batches} batches")
     return 0
+
+
+@contextlib.contextmanager
+def profiled(trace_dir: str):
+    """Run the body under ``jax.profiler`` into ``trace_dir``: device
+    operations plus every ``TraceAnnotation`` (``host_tracer_level`` 2),
+    without per-call Python events."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 2
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
 
 
 def main(argv=None):
@@ -213,6 +231,11 @@ def main(argv=None):
     ap.add_argument("--trace-out", default="",
                     help="write a Chrome/Perfetto trace-event JSON of the "
                          "serve run here (open in ui.perfetto.dev)")
+    ap.add_argument("--profile-dir", default="",
+                    help="run the serve loop under jax.profiler into this "
+                         "directory, with every program span annotated in "
+                         "the profiler's trace: device operations and host "
+                         "spans on one clock (TensorBoard, Perfetto)")
     ap.add_argument("--metrics-out", default="",
                     help="write a metrics-registry snapshot JSON here")
     ap.add_argument("--flight-dir", default="",
@@ -297,11 +320,14 @@ def main(argv=None):
             r.deadline = r.arrival + args.deadline_ms
 
     # Observability wiring (DESIGN.md §6): --trace-out lights up the
-    # process-default tracer, --flight-dir adds an on-disk flight recorder.
-    # The engine still auto-creates an in-memory flight recorder under
-    # --inject-faults even when none of these flags are given.
+    # process-default tracer, --profile-dir an annotating one of its own,
+    # --flight-dir adds an on-disk flight recorder. The engine still
+    # auto-creates an in-memory flight recorder under --inject-faults even
+    # when none of these flags are given.
     tracer = default_tracer()
-    if args.trace_out:
+    if args.profile_dir:
+        tracer = Tracer(enabled=True, annotate=True)
+    elif args.trace_out:
         tracer.enabled = True
     flight = FlightRecorder(out_dir=args.flight_dir) if args.flight_dir \
         else None
@@ -364,7 +390,9 @@ def main(argv=None):
     import time as _time
     t_serve0 = _time.perf_counter()
     try:
-        stats = eng.run()
+        with (profiled(args.profile_dir) if args.profile_dir
+              else contextlib.nullcontext()):
+            stats = eng.run()
     except Exception as exc:
         from repro.serve.faults import InjectedCrash
         if not isinstance(exc, InjectedCrash):
@@ -377,6 +405,9 @@ def main(argv=None):
         print(f"# {exc}{where}")
         eng.close()   # stop compile workers for a clean interpreter exit
         return 1
+    if args.profile_dir:
+        tracer.enabled = False
+        print(f"# wrote a profiler trace under {args.profile_dir}")
 
     pct = stats.latency_percentiles()
     print(f"{stats.requests_done} requests ({stats.tokens_out} tokens, "

@@ -11,16 +11,17 @@ The registry itself is a flat name -> instrument map:
 - :class:`Counter` — monotone float/int accumulator (``inc``),
 - :class:`Gauge` — last-write-wins value (``set``),
 - :class:`Histogram` — observations with fixed bucket boundaries *and*
-  retained raw samples, so snapshots carry both cumulative ``le_*`` bucket
-  counts (cheap, mergeable) and exact p50/p95/p99 (what the launcher and
-  BENCH payloads report).
+  the most recent raw samples (:data:`MAX_SAMPLES`), so snapshots carry
+  both cumulative ``le_*`` bucket counts over every observation (cheap,
+  mergeable) and exact p50/p95/p99 over the retained samples (what the
+  launcher and BENCH payloads report).
 
 ``MetricsRegistry.snapshot()`` returns a plain JSON-ready dict; the serve
 launcher dumps it behind ``--metrics-out`` and every benchmark stamps it
 into its ``BENCH_*.json`` via ``benchmarks.common.platform_payload``.
 
 All instruments share their registry's lock. Observation cost is one lock
-acquire + list append — negligible next to a serve round, and the obs-smoke
+acquire + deque append — negligible next to a serve round, and the obs-smoke
 overhead gate covers the enabled path end to end.
 """
 
@@ -28,10 +29,16 @@ from __future__ import annotations
 
 import threading
 from bisect import bisect_left
+from collections import deque
 
 # Default histogram boundaries (seconds): spans µs-scale host packing
 # through multi-second XLA compiles.
 DEFAULT_BOUNDARIES = (1e-5, 1e-4, 1e-3, 1e-2, 0.1, 0.5, 1.0, 5.0, 30.0)
+
+# Raw samples a histogram keeps: percentiles are exact up to this many
+# observations, and over the latest this many after, so a long-running
+# server's memory stays bounded.
+MAX_SAMPLES = 65536
 
 
 def percentile(xs, q: float) -> float:
@@ -92,10 +99,12 @@ class Histogram:
     """Observations with fixed cumulative buckets + retained samples.
 
     ``boundaries`` are upper edges; an observation lands in the first
-    bucket whose edge is >= the value, with a final +inf bucket. Raw
-    samples are retained so ``percentiles()`` is exact (matches
-    ``numpy.percentile`` — verified in tests) rather than
-    bucket-interpolated.
+    bucket whose edge is >= the value, with a final +inf bucket. The last
+    :data:`MAX_SAMPLES` raw samples are retained so ``percentiles()`` is
+    exact (matches ``numpy.percentile`` — verified in tests) rather than
+    bucket-interpolated, up to that many observations; past it they are
+    the percentiles of the most recent :data:`MAX_SAMPLES`. ``count``,
+    ``sum``, ``min``, ``max`` and the buckets cover every observation.
     """
 
     def __init__(self, name: str, lock: threading.Lock,
@@ -104,19 +113,21 @@ class Histogram:
         self._lock = lock
         self.boundaries = tuple(sorted(float(b) for b in boundaries))
         self.bucket_counts = [0] * (len(self.boundaries) + 1)
-        self.samples: list[float] = []
+        self.samples: deque[float] = deque(maxlen=MAX_SAMPLES)
+        self.count = 0
         self.sum = 0.0
+        self.min = float("inf")
+        self.max = float("-inf")
 
     def observe(self, v: float) -> None:
         v = float(v)
         with self._lock:
             self.bucket_counts[bisect_left(self.boundaries, v)] += 1
             self.samples.append(v)
+            self.count += 1
             self.sum += v
-
-    @property
-    def count(self) -> int:
-        return len(self.samples)
+            self.min = min(self.min, v)
+            self.max = max(self.max, v)
 
     def percentiles(self, qs=(50, 95, 99)) -> dict:
         with self._lock:
@@ -127,10 +138,10 @@ class Histogram:
         with self._lock:
             xs = list(self.samples)
             buckets = list(self.bucket_counts)
-        out = {"count": len(xs), "sum": self.sum}
-        if xs:
-            out["min"] = min(xs)
-            out["max"] = max(xs)
+            out = {"count": self.count, "sum": self.sum}
+            if self.count:
+                out["min"] = self.min
+                out["max"] = self.max
         out.update({f"p{q}": percentile(xs, q) for q in (50, 95, 99)})
         cum = 0
         le = {}

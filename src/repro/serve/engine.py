@@ -687,7 +687,8 @@ class ServeEngine:
 
     def step(self) -> None:
         """One scheduler round: admit, build wave graphs, execute, feed back."""
-        self._poll_compiles()
+        with self.tracer.span("serve.poll_compiles"):
+            self._poll_compiles()
         if self._injector is not None:
             # Elastic-mesh fault hooks fire at the round boundary, before
             # any of this round's work: a lost replica resizes the mesh (its
@@ -710,7 +711,7 @@ class ServeEngine:
         tr = self.tracer
         tr.mark_round(self._round)
         t_round = time.perf_counter()
-        with tr.span("serve.round", round=self._round):
+        with tr.counted_span("serve.round", round=self._round):
             # A plan speculatively packed during round t-1's in-flight
             # dispatch is promoted here if the world still matches the
             # prediction; otherwise (or with no speculation) the serial
@@ -927,14 +928,17 @@ class ServeEngine:
         if tier != "interpreted":
             qkey = None
             try:
-                qkey = ((fam, ex.pack_for(graph, pol, es).spec)
-                        if tier == "bucketed"
-                        else (fam, graph.topology_key()))
-                if not self.quarantine.blocks(qkey, self._round):
+                with self.tracer.span("round.lookup"):
+                    qkey = ((fam, ex.pack_for(graph, pol, es).spec)
+                            if tier == "bucketed"
+                            else (fam, graph.topology_key()))
+                    blocked = self.quarantine.blocks(qkey, self._round)
+                if not blocked:
                     if self._injector is not None:
                         self._injector.on_exec(self._round, tier)
                     res = ex.run(graph, pol, es, params=params)
-                    self.quarantine.clear(qkey)
+                    with self.tracer.span("round.settle"):
+                        self.quarantine.clear(qkey)
                     return res, tier
             except Exception as exc:
                 if qkey is not None:
@@ -956,27 +960,24 @@ class ServeEngine:
         bridge through a coarser already-compiled bucket (``coarse``), else
         the interpreted floor. The first compiled round after degraded ones
         is the hot-swap."""
-        jobsig = _sig_digest(("cjob", fam, graph.topology_key(),
-                              policy_cache_key(pol)))
-        pack = ex.pack_ready(graph, pol)
-        blocked = (pack is not None
-                   and self.quarantine.blocks((fam, pack.spec), self._round))
-        if pack is not None and not blocked:
+        jobsig, pack, blocked, ready = self._lookup(fam, ex, pol, graph,
+                                                    params)
+        if ready:
             qkey = (fam, pack.spec)
-            if ex.executable_ready(pack, params):
-                try:
-                    if self._injector is not None:
-                        self._injector.on_exec(self._round, "bucketed")
-                    res = ex.run_packed(graph, pack, es, params=params)
+            try:
+                if self._injector is not None:
+                    self._injector.on_exec(self._round, "bucketed")
+                res = ex.run_packed(graph, pack, es, params=params)
+                with self.tracer.span("round.settle"):
                     self.quarantine.clear(qkey)
                     self._note_hotswap(jobsig, fam)
-                    return res, "bucketed"
-                except Exception as exc:
-                    self.quarantine.record_failure(qkey, self._round, exc)
-                    self._contained()
-                    res = self._interp_executor(fam).run(graph, pol, es,
-                                                         params=params)
-                    return res, "interpreted"
+                return res, "bucketed"
+            except Exception as exc:
+                self.quarantine.record_failure(qkey, self._round, exc)
+                self._contained()
+                res = self._interp_executor(fam).run(graph, pol, es,
+                                                     params=params)
+                return res, "interpreted"
         if not blocked:
             # This round serves degraded while the build is in flight:
             # remember the sig so its first compiled round counts as a
@@ -989,6 +990,22 @@ class ServeEngine:
                 return cres, "coarse"
         res = self._interp_executor(fam).run(graph, pol, es, params=params)
         return res, "interpreted"
+
+    def _lookup(self, fam: str, ex, pol, graph, params) -> tuple:
+        """The async tiers' dispatch-side probes, in one ``round.lookup``
+        span: ``(jobsig, pack, blocked, ready)`` — the build job's digest,
+        the cached pack (or ``None``), whether its bucket is quarantined,
+        and whether its executable is built and may run now."""
+        with self.tracer.span("round.lookup"):
+            jobsig = _sig_digest(("cjob", fam, graph.topology_key(),
+                                  policy_cache_key(pol)))
+            pack = ex.pack_ready(graph, pol)
+            blocked = (pack is not None
+                       and self.quarantine.blocks((fam, pack.spec),
+                                                  self._round))
+            ready = (pack is not None and not blocked
+                     and ex.executable_ready(pack, params))
+        return jobsig, pack, blocked, ready
 
     def _try_coarse(self, fam: str, ex, pol, es, graph, params, coarse_fn):
         """Bridge tier: re-pad this round into a *coarser count bucket*
@@ -1248,21 +1265,17 @@ class ServeEngine:
         entry list — its counters predict commit t exactly."""
         if self._spec is not None:
             self._cancel_spec()
-        for e in entries:
-            req = e.req
-            fed_only = (req.feed is not None
-                        and req.n_fed + 1 < len(req.feed))
-            if not fed_only and len(req.out) + 1 >= req.max_new:
-                return
         round1 = self._round + 1
         delay = (self._injector.round_delay(self._round)
                  if self._injector is not None else 0.0)
         now1 = max(self._now + delay + 1.0, float(round1))
         sched = self.scheduler
-        for req in list(sched.active) + list(sched.waiting_lm):
-            if self._expired_at(req, now1):
-                return
-        snap = self._spec_snapshot()
+        with self.tracer.span("round.spec_check"):
+            safe = self._spec_safe(entries, now1)
+        if not safe:
+            return
+        with self.tracer.span("round.spec_snapshot"):
+            snap = self._spec_snapshot()
         feed_undo: list = []
         try:
             with self.tracer.span("round.schedule", overlap=True,
@@ -1311,6 +1324,21 @@ class ServeEngine:
         self._spec = _Speculation(round1, now1, nplan, graph,
                                   list(nentries), snap, feed_undo)
         self.stats.n_overlapped_packs += 1
+
+    def _spec_safe(self, entries: list, now1: float) -> bool:
+        """False when commit t could reshape round t+1's plan: an entry
+        predicted to complete frees its slot, or a deadline expires by
+        ``now1``."""
+        for e in entries:
+            req = e.req
+            fed_only = (req.feed is not None
+                        and req.n_fed + 1 < len(req.feed))
+            if not fed_only and len(req.out) + 1 >= req.max_new:
+                return False
+        sched = self.scheduler
+        return not any(self._expired_at(req, now1)
+                       for req in list(sched.active)
+                       + list(sched.waiting_lm))
 
     def _promote_spec(self) -> RoundPlan | None:
         """Commit-boundary guard: hand the speculative plan to step() iff
@@ -1365,9 +1393,11 @@ class ServeEngine:
                                            coarse_fn)
         qkey = None
         try:
-            pack = ex.pack_for(graph, pol, es)
-            qkey = (fam, pack.spec)
-            if not self.quarantine.blocks(qkey, self._round):
+            with self.tracer.span("round.lookup"):
+                pack = ex.pack_for(graph, pol, es)
+                qkey = (fam, pack.spec)
+                blocked = self.quarantine.blocks(qkey, self._round)
+            if not blocked:
                 if self._injector is not None:
                     self._injector.on_exec(self._round, "bucketed")
                 handle = ex.dispatch_packed(graph, pack, es, params=params)
@@ -1385,25 +1415,19 @@ class ServeEngine:
         ready -> submit the build and serve this round eagerly through the
         coarse bridge or the interpreted floor (transitional tiers — no
         overlap is lost by not pipelining them)."""
-        jobsig = _sig_digest(("cjob", fam, graph.topology_key(),
-                              policy_cache_key(pol)))
-        pack = ex.pack_ready(graph, pol)
-        blocked = (pack is not None
-                   and self.quarantine.blocks((fam, pack.spec),
-                                              self._round))
-        if pack is not None and not blocked:
+        jobsig, pack, blocked, ready = self._lookup(fam, ex, pol, graph,
+                                                    params)
+        if ready:
             qkey = (fam, pack.spec)
-            if ex.executable_ready(pack, params):
-                try:
-                    if self._injector is not None:
-                        self._injector.on_exec(self._round, "bucketed")
-                    handle = ex.dispatch_packed(graph, pack, es,
-                                                params=params)
-                    return handle, "bucketed", qkey, jobsig
-                except Exception as exc:
-                    self.quarantine.record_failure(qkey, self._round, exc)
-                    self._contained()
-                    return self._floor_handle(fam, graph, params)
+            try:
+                if self._injector is not None:
+                    self._injector.on_exec(self._round, "bucketed")
+                handle = ex.dispatch_packed(graph, pack, es, params=params)
+                return handle, "bucketed", qkey, jobsig
+            except Exception as exc:
+                self.quarantine.record_failure(qkey, self._round, exc)
+                self._contained()
+                return self._floor_handle(fam, graph, params)
         if not blocked:
             self._submit_compile_job(fam, ex, pol, graph, jobsig, params)
             self._awaiting.add(jobsig)
@@ -1427,20 +1451,24 @@ class ServeEngine:
         return _ReadyRound(res), "interpreted", None, None
 
     def _run_lm_round_pipelined(self, plan, wl, pool, graph, entries,
-                                coarse_fn) -> None:
+                                coarse_fn):
         """Two-stage round: dispatch round t without blocking, overlap the
         host-side plan+pack of round t+1 with the in-flight device work,
         then commit — block on t's arenas, scatter, feed. A commit failure
         (device error surfacing at block, or an injected commit fault)
         cancels the speculation *first*, so the containment ladder and the
-        re-planned round t+1 both see rolled-back state."""
-        rd = self._dispatch_lm(graph, pool, coarse_fn)
+        re-planned round t+1 both see rolled-back state. Returns the
+        round's result (``None`` after isolation), which the caller
+        releases with the round graph."""
+        with self.tracer.span("round.dispatch"):
+            rd = self._dispatch_lm(graph, pool, coarse_fn)
         if rd is None:
             self._contained()
             return self._isolate_lm_round(plan, wl, True)
         handle, tier, qkey, jobsig = rd
         if self.pipeline and handle.pending:
-            self._speculate_next(plan, entries)
+            with self.tracer.span("round.speculate"):
+                self._speculate_next(plan, entries)
         try:
             if self._injector is not None:
                 self._injector.on_commit(self._round)
@@ -1452,9 +1480,10 @@ class ServeEngine:
             self._contained()
             return self._isolate_lm_round(plan, wl, True)
         try:
-            res = handle.block()
-            if qkey is not None:
-                self.quarantine.clear(qkey)
+            with self.tracer.span("round.settle"):
+                res = handle.block()
+                if qkey is not None:
+                    self.quarantine.clear(qkey)
         except Exception as exc:
             self._cancel_spec()
             if qkey is not None:
@@ -1470,6 +1499,7 @@ class ServeEngine:
         with self.tracer.span("round.feed"):
             self._feed_tokens(entries, toks, time.perf_counter(),
                               self._shard_stats[0])
+        return res
 
     def _scatter_commit(self, res, entries, wl, pool):
         """Commit one lm round's results: next-token argmax plus the state
@@ -1479,11 +1509,22 @@ class ServeEngine:
         commit run as one jitted dispatch instead of ~2 eager dispatches
         per state field; the interpreted floor's ``ExecResult`` takes the
         eager per-field path."""
+        tr = self.tracer
         o_ids = [e.o_node for e in entries]
-        cell_ids = [e.cell_node for e in entries]
-        slots = np.asarray([e.slot for e in entries], np.int32)
-        fields = list(wl.state_fields)
-        if hasattr(res, "arena_rows"):
+        fused = hasattr(res, "arena_rows")
+        if not fused:
+            # The eager path reads the logits back before it dispatches the
+            # scatters, so the read does not queue behind them.
+            with tr.span("round.readback"):
+                toks = np.argmax(np.asarray(res.field("y", o_ids)), axis=-1)
+        with tr.span("round.commit"):
+            cell_ids = [e.cell_node for e in entries]
+            slots = np.asarray([e.slot for e in entries], np.int32)
+            fields = list(wl.state_fields)
+            if not fused:
+                for f in fields:
+                    pool[f] = pool[f].at[slots].set(res.field(f, cell_ids))
+                return toks
             y_arena, y_rows = res.arena_rows("y", o_ids)
             arenas, rows = [], []
             for f in fields:
@@ -1495,12 +1536,8 @@ class ServeEngine:
                                             [pool[f] for f in fields])
             for f, p in zip(fields, new_pools):
                 pool[f] = p
+        with tr.span("round.readback"):
             return np.asarray(toks)
-        ys = np.asarray(res.field("y", o_ids))
-        toks = np.argmax(ys, axis=-1)
-        for f in fields:
-            pool[f] = pool[f].at[slots].set(res.field(f, cell_ids))
-        return toks
 
     # -- per-family round execution -----------------------------------------
 
@@ -1548,6 +1585,7 @@ class ServeEngine:
                     pool[f] = pool[f].at[e.slot].set(row)
 
     def _feed_tokens(self, entries, toks, now: float, st: ServeStats) -> None:
+        n_out = 0
         for e, tok in zip(entries, toks):
             req = e.req
             if req.feed is not None and req.n_fed < len(req.feed):
@@ -1562,9 +1600,12 @@ class ServeEngine:
                                   round=self._round)
             req.out.append(int(tok))
             st.tokens_out += 1
-            self._metrics.counter("serve.tokens_out").inc()
+            n_out += 1
             if req.done:
                 self._finish(req, now, st)
+        if n_out:
+            # One registry update a round, not one lock round-trip a token.
+            self._metrics.counter("serve.tokens_out").inc(n_out)
 
     def _run_lm_round(self, plan) -> None:
         if self.n_shards > 1:
@@ -1618,27 +1659,34 @@ class ServeEngine:
             def coarse_fn(count):
                 return build_lm_feed_round_graph(plan, count=count)[0]
         if self.pipeline and feed_mode:
-            return self._run_lm_round_pipelined(plan, wl, pool, graph,
-                                                entries, coarse_fn)
-        try:
-            res, tier = self._exec_graph("lm", graph,
-                                         params={"slots": pool},
-                                         coarse_fn=coarse_fn)
-            if self._injector is not None:
-                # Commit-fault parity with the pipelined path: the serial
-                # loop's commit boundary sits right after execution.
-                self._injector.on_commit(self._round)
-        except Exception:
-            # Even the interpreted floor failed on the merged graph:
-            # isolate per entry so one bad request cannot starve the rest.
-            self._contained()
-            return self._isolate_lm_round(plan, wl, feed_mode)
-        self._note_tier(tier)
-        with self.tracer.span("round.scatter"):
-            toks = self._scatter_commit(res, entries, wl, pool)
-        with self.tracer.span("round.feed"):
-            self._feed_tokens(entries, toks, time.perf_counter(),
-                              self._shard_stats[0])
+            res = self._run_lm_round_pipelined(plan, wl, pool, graph,
+                                               entries, coarse_fn)
+        else:
+            try:
+                res, tier = self._exec_graph("lm", graph,
+                                             params={"slots": pool},
+                                             coarse_fn=coarse_fn)
+                if self._injector is not None:
+                    # Commit-fault parity with the pipelined path: the
+                    # serial loop's commit boundary sits right after
+                    # execution.
+                    self._injector.on_commit(self._round)
+            except Exception:
+                # Even the interpreted floor failed on the merged graph:
+                # isolate per entry so one bad request cannot starve the
+                # rest.
+                self._contained()
+                return self._isolate_lm_round(plan, wl, feed_mode)
+            self._note_tier(tier)
+            with self.tracer.span("round.scatter"):
+                toks = self._scatter_commit(res, entries, wl, pool)
+            with self.tracer.span("round.feed"):
+                self._feed_tokens(entries, toks, time.perf_counter(),
+                                  self._shard_stats[0])
+        with self.tracer.span("round.release"):
+            # The round's last references to its graph and results: freeing
+            # their nodes and device buffers is host work of every round.
+            del graph, entries, promoted, res
 
     def _isolate_lm_round(self, plan, wl, feed_mode: bool) -> None:
         """Request-level lm isolation: re-run this round one live entry at
@@ -1710,8 +1758,9 @@ class ServeEngine:
             # build runs on a compile worker; until it lands, rounds serve
             # per-shard through the already-degraded path instead of
             # blocking the loop on the (expensive) shard_map lowering.
-            ready, jobsig = self._lm_sharded_ready(ex, [g for g, _ in built],
-                                                   pool)
+            with self.tracer.span("round.lookup"):
+                ready, jobsig = self._lm_sharded_ready(
+                    ex, [g for g, _ in built], pool)
             if not ready:
                 return self._lm_round_sharded_degrade(ex, built, wl, pool)
         try:
@@ -1729,7 +1778,8 @@ class ServeEngine:
             self._contained()
             return self._lm_round_sharded_degrade(ex, built, wl, pool)
         now = time.perf_counter()
-        with self.tracer.span("round.scatter"):
+        tr = self.tracer
+        with tr.span("round.scatter"):
             # One combined scatter per state field across all shards (not K
             # copy-on-write pool updates): collect every live entry's
             # (shard, slot, state) first, write once. State values stay on
@@ -1742,20 +1792,24 @@ class ServeEngine:
             for s, (res, (_, entries)) in enumerate(zip(results, built)):
                 if not entries:
                     continue
-                ys = np.asarray(res.field("y", [e.o_node for e in entries]))
-                cell_ids = [e.cell_node for e in entries]
-                shards_ix.extend([s] * len(entries))
-                slots_ix.extend(e.slot for e in entries)
-                for f in wl.state_fields:
-                    state_vals[f].append(res.field(f, cell_ids))
+                with tr.span("round.readback"):
+                    ys = np.asarray(res.field("y",
+                                              [e.o_node for e in entries]))
+                with tr.span("round.commit"):
+                    cell_ids = [e.cell_node for e in entries]
+                    shards_ix.extend([s] * len(entries))
+                    slots_ix.extend(e.slot for e in entries)
+                    for f in wl.state_fields:
+                        state_vals[f].append(res.field(f, cell_ids))
                 fed.append((entries, np.argmax(ys, axis=-1),
                             self._shard_stats[s]))
-            shards_arr = np.asarray(shards_ix, np.int32)
-            slots_arr = np.asarray(slots_ix, np.int32)
-            for f in wl.state_fields:
-                pool[f] = pool[f].at[shards_arr, slots_arr].set(
-                    jnp.concatenate(state_vals[f]))
-        with self.tracer.span("round.feed"):
+            with tr.span("round.commit"):
+                shards_arr = np.asarray(shards_ix, np.int32)
+                slots_arr = np.asarray(slots_ix, np.int32)
+                for f in wl.state_fields:
+                    pool[f] = pool[f].at[shards_arr, slots_arr].set(
+                        jnp.concatenate(state_vals[f]))
+        with tr.span("round.feed"):
             for entries, toks, st in fed:
                 self._feed_tokens(entries, toks, now, st)
 

@@ -94,3 +94,25 @@ def test_chip_smoke_refuses_a_non_tpu_backend():
     spec.loader.exec_module(chip_smoke)
     with pytest.raises(SystemExit, match="needs a TPU"):
         chip_smoke.require_tpu(jax.devices())
+
+
+def test_serve_profile_dir_writes_program_spans_beside_the_device(tmp_path):
+    """``--profile-dir``: the serve loop runs under ``jax.profiler`` with
+    an annotating tracer, so the profiler's trace holds the program's
+    round spans."""
+    import glob
+
+    import jax
+
+    from repro.launch.serve import main
+
+    out = tmp_path / "profile"
+    assert main(["--families", "lm", "--requests", "2", "--max-new", "2",
+                 "--model-size", "8", "--max-slots", "2",
+                 "--profile-dir", str(out)]) == 0
+    paths = glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)
+    assert paths
+    names = {ev.name for plane in
+             jax.profiler.ProfileData.from_file(paths[0]).planes
+             for line in plane.lines for ev in line.events}
+    assert {"serve.run", "serve.round", "round.lm"} <= names

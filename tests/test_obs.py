@@ -4,6 +4,8 @@ numpy, flight-recorder dumps on injected faults, disabled-path no-ops under
 thread hammering, and ServeStats <-> metrics cross-validation on a real
 engine run."""
 
+import gc
+import glob
 import json
 import threading
 
@@ -11,8 +13,10 @@ import numpy as np
 import pytest
 
 from repro.models.workloads import make_workload
-from repro.obs import FlightRecorder, Obs, Tracer
-from repro.obs.metrics import (MetricsRegistry, latency_summary, percentile)
+from repro.obs import FlightRecorder, Obs, Tracer, metrics
+from repro.obs.metrics import (Histogram, MetricsRegistry, latency_summary,
+                               percentile)
+from repro.obs import tracer as tracer_mod
 from repro.obs.tracer import (NULL_SPAN, NULL_TRACER, validate_chrome_trace)
 from repro.serve import ServeEngine, lm_request
 from repro.serve.faults import FaultInjector, Quarantine, poison_requests
@@ -51,7 +55,7 @@ def test_span_nesting_depth_and_balance():
         tr.event("ev", x=1)
     assert tr.depth() == 0
     assert tr.open_spans() == 0
-    names = [e["name"] for e in tr.events]
+    names = [e["name"] for e in tr.events if e["cat"] != "gc"]
     assert names == ["b", "ev", "a"]     # spans record on exit
     a, b = tr.spans("a")[0], tr.spans("b")[0]
     assert a["ts"] <= b["ts"]
@@ -65,7 +69,8 @@ def test_span_balanced_even_when_body_raises():
             with tr.span("inner"):
                 raise ValueError("boom")
     assert tr.open_spans() == 0
-    assert [s["name"] for s in tr.spans()] == ["inner", "outer"]
+    assert [s["name"] for s in tr.spans() if s["cat"] != "gc"] == [
+        "inner", "outer"]
 
 
 def test_ring_keeps_last_rounds_and_counts_drops():
@@ -134,10 +139,172 @@ def test_tracer_thread_hammer_stays_balanced(enabled):
         t.join()
     assert errs == []
     assert tr.open_spans() == 0
-    n = len(tr.spans())
+    n = len(tr.spans("outer")) + len(tr.spans("inner"))
     assert n == (8 * 200 * 2 if enabled else 0)
     if enabled:
         assert validate_chrome_trace(tr.to_chrome()) == []
+
+
+# -- the runtime beside the program ------------------------------------------
+
+
+def _runtime_hooks():
+    """What the tracer may hook: ``gc.callbacks`` and JAX's listeners."""
+    import jax._src.monitoring as mon
+
+    return (list(gc.callbacks), mon.get_event_time_span_listeners(),
+            mon.get_event_duration_listeners(), mon.get_event_listeners())
+
+
+@pytest.mark.parametrize("gen", [1, 2])
+def test_gc_collect_span_and_collection_under_the_tracer_lock(gen):
+    """A collection shows up as a ``gc.collect`` span, and one that starts
+    while its own thread holds the tracer's lock (as ``events`` or
+    ``mark_round`` do when they allocate) completes instead of
+    deadlocking in the hook."""
+    tr = Tracer(enabled=True)
+    done = []
+
+    def body():
+        with tr._lock:
+            gc.collect(gen)
+        done.append(True)
+
+    t = threading.Thread(target=body, daemon=True)
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive() and done
+    spans = tr.spans("gc.collect")
+    assert spans and spans[-1]["args"]["gen"] == gen
+    assert "collected" in spans[-1]["args"]
+    assert tr.gc_s > 0
+    tr.enabled = False
+    assert tr.open_spans() == 0
+
+
+def test_tracer_thread_hammer_with_collections_stays_balanced():
+    """Eight threads open spans while collections run on them: the GC
+    hook's one open-collection slot and the re-entrant lock keep every
+    stack balanced, and each generation-1/2 collection is one span."""
+    tr = Tracer(enabled=True)
+    seen = []
+
+    def count(phase, info):
+        if phase == "stop" and info["generation"] >= 1:
+            seen.append(info["generation"])
+
+    gc.callbacks.append(count)      # after the tracer's hook: sees no more
+    errs = []
+
+    def work(tid):
+        try:
+            for i in range(200):
+                with tr.span("outer", tid=tid):
+                    with tr.span("inner", i=i):
+                        junk = [[j] for j in range(50)]
+                    if i % 25 == tid % 25:
+                        gc.collect(1 + i % 2)
+                del junk
+                assert tr.depth() == 0
+        except Exception as exc:          # pragma: no cover
+            errs.append(exc)
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in range(8)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        gc.callbacks.remove(count)
+        tr.enabled = False
+    assert errs == [] and not any(t.is_alive() for t in threads)
+    assert tr.open_spans() == 0
+    assert len(tr.spans("outer")) == len(tr.spans("inner")) == 8 * 200
+    collects = tr.spans("gc.collect")
+    assert seen        # a collect that meets another one running is skipped
+    assert len(collects) == len(seen)
+    assert sorted(s["args"]["gen"] for s in collects) == sorted(seen)
+    assert validate_chrome_trace(tr.to_chrome()) == []
+
+
+def test_ring_tracer_installs_no_hooks_and_stamps_no_counters():
+    """The flight recorder's always-on ring tracer records program spans
+    only: no GC hook, no JAX listener, no per-round counters."""
+    gc.collect()
+    before = _runtime_hooks()
+    tr = Tracer(enabled=True, ring=4)
+    assert _runtime_hooks() == before
+    tr.mark_round(0)
+    with tr.counted_span("serve.round", round=0):
+        gc.collect(2)
+    tr.enabled = False
+    assert _runtime_hooks() == before
+    assert [s["name"] for s in tr.spans()] == ["serve.round"]
+    assert tr.spans("serve.round")[0]["args"] == {"round": 0}
+
+
+def test_disabled_tracer_leaves_gc_and_jax_listeners_as_they_were():
+    gc.collect()          # finalize any dropped tracer before the snapshot
+    before = _runtime_hooks()
+    tr = Tracer(enabled=False)
+    with tr.span("x"):
+        gc.collect()
+    assert _runtime_hooks() == before
+    tr.enabled = True
+    assert _runtime_hooks() != before
+    tr.enabled = False
+    assert _runtime_hooks() == before
+    tr.enabled = True
+    tr.enabled = False
+    assert _runtime_hooks() == before
+    assert tr.spans("x") == []
+
+
+def test_jit_retrace_in_a_traced_round_is_a_jax_trace_span():
+    """A jit trace and compile inside an engine round land as
+    ``jax.trace`` / ``jax.compile`` spans naming the function and the
+    round. Width 12 is this test's own, so the engine's jitted helpers
+    trace anew here."""
+    tr = Tracer(enabled=True)
+    _serve({"lm": make_workload("ChainLM", 12)}, _lm_trace(n=2, max_new=2),
+           obs=Obs(tracer=tr))
+    tr.enabled = False
+    rounds = {s["args"]["round"] for s in tr.spans("serve.round")}
+    for name in ("jax.trace", "jax.compile"):
+        spans = tr.spans(name)
+        assert spans, name
+        assert all(s["args"]["fun_name"] for s in spans)
+        assert any(s["args"]["round"] in rounds for s in spans)
+    assert any("_fused_zero" in s["args"]["fun_name"]
+               and s["args"]["round"] in rounds
+               for s in tr.spans("jax.trace"))
+    assert validate_chrome_trace(tr.to_chrome()) == []
+
+
+def test_annotate_writes_span_names_into_the_profiler_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 2
+    tr = Tracer(enabled=True, annotate=True)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("serve.round", round=0):
+            with tr.span("round.commit"):
+                jnp.arange(4.0).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+        tr.enabled = False
+    paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert paths
+    names = {ev.name for plane in
+             jax.profiler.ProfileData.from_file(paths[0]).planes
+             for line in plane.lines for ev in line.events}
+    assert {"serve.round", "round.commit"} <= names
+    assert [s["name"] for s in tr.spans() if s["cat"] == "serve"] == [
+        "round.commit", "serve.round"]
 
 
 # -- metrics -----------------------------------------------------------------
@@ -169,6 +336,22 @@ def test_histogram_buckets_and_percentiles():
                                "le_inf": 5}
     for q in (50, 95, 99):
         assert snap[f"p{q}"] == pytest.approx(float(np.percentile(xs, q)))
+
+
+def test_histogram_retains_a_bounded_window_of_samples(monkeypatch):
+    """Past ``MAX_SAMPLES`` observations the percentiles are the latest
+    window's, exact; count, sum, min, max and buckets cover them all."""
+    monkeypatch.setattr(metrics, "MAX_SAMPLES", 8)
+    h = Histogram("lat", threading.Lock(), boundaries=(5.0, 15.0))
+    for x in range(20):
+        h.observe(float(x))
+    assert len(h.samples) == 8
+    snap = h.snapshot()
+    assert (snap["count"], snap["sum"], snap["min"], snap["max"]) == (
+        20, 190.0, 0.0, 19.0)
+    assert snap["buckets"] == {"le_5": 6, "le_15": 16, "le_inf": 20}
+    assert snap["p50"] == pytest.approx(float(np.percentile(range(12, 20),
+                                                            50)))
 
 
 def test_registry_get_or_create_and_kind_mismatch():
@@ -272,6 +455,38 @@ def test_engine_trace_covers_rounds_and_stats_match(lm_workloads):
     # request lifecycle instants present for each completed request
     done = [e for e in tr.events if e["name"] == "req.completed"]
     assert len(done) == stats.requests_done
+
+
+def test_pipelined_lm_rounds_have_phase_spans_and_round_counters(
+        lm_workloads):
+    """Every lm round of a pipelined CPU run names its dispatch-side
+    lookup, its commit and its readback, and ``serve.round`` carries the
+    loop thread's CPU and GC time."""
+    tr = Tracer(enabled=True)
+    _, stats = _serve(lm_workloads, _lm_trace(n=6, max_new=4),
+                      obs=Obs(tracer=tr), pipeline=True)
+    tr.enabled = False
+    assert stats.n_pipelined_rounds > 0
+    spans = tr.spans()
+    lms = [s for s in spans if s["name"] == "round.lm"]
+    assert len(lms) == stats.n_rounds
+
+    def inside(outer, name):
+        return [s for s in spans if s["name"] == name
+                and s["tid"] == outer["tid"] and outer["ts"] <= s["ts"]
+                and s["ts"] + s["dur"] <= outer["ts"] + outer["dur"]]
+
+    for lm in lms:
+        for name in ("round.lookup", "round.commit", "round.readback"):
+            assert len(inside(lm, name)) == 1, name
+    for sc in tr.spans("round.scatter"):
+        assert inside(sc, "round.commit") and inside(sc, "round.readback")
+    for r in tr.spans("serve.round"):
+        assert r["args"]["cpu_ms"] >= 0 and r["args"]["gc_ms"] >= 0
+        if tracer_mod.resource is not None:    # the host counts them
+            assert {"nivcsw", "majflt"} <= set(r["args"])
+    assert len(tr.spans("serve.poll_compiles")) == len(tr.spans("serve.round"))
+    assert tr.open_spans() == 0
 
 
 def test_engine_default_obs_records_nothing(lm_workloads):
