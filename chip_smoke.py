@@ -171,7 +171,8 @@ def summarize(name: str, stats) -> dict:
             "compile_jobs_submitted", "compile_jobs_landed",
             "compile_jobs_retried", "compile_jobs_timed_out",
             "compile_jobs_quarantined", "n_hotswaps", "n_pipelined_rounds",
-            "n_sharded_dispatches", "n_shard_fallback_rounds", "wall_s",
+            "n_commit_in_program", "n_sharded_dispatches",
+            "n_shard_fallback_rounds", "wall_s",
             "lower_s", "lower_bg_s")
     d = {k: getattr(stats, k) for k in keys}
     d["tier_rounds"] = dict(stats.tier_rounds)

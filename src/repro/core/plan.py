@@ -34,7 +34,11 @@ specialization:
   reserved trash row, so no explicit select enters the program.  Steps
   whose impl exposes a ``fused_gather`` path run the fused Pallas
   gather→cell kernel (:mod:`repro.kernels.fused_gather_cell`) straight off
-  the arenas instead of materializing gathered operands.
+  the arenas instead of materializing gathered operands.  An optional
+  commit stage (:class:`CommitSpec`) runs after the last step: it reads
+  each fragment's output and state rows and returns tokens and updated
+  state pools from the same program, so a recurrent serve round is one
+  dispatch.
 
 - **Sharded bucketed execution** (:class:`ShardedBucketedPlanExecutor`).
   K shards' runtime operands — index packs, aux vectors, arena pools,
@@ -721,13 +725,53 @@ class BucketSpec:
         return sum(s.width for s in self.steps)
 
 
+@dataclass(frozen=True)
+class CommitSpec:
+    """An optional last stage of the bucket program, for graphs made of
+    recurrent fragments ``slot_type -> ... -> state node -> output node``
+    whose state lives in pools threaded through ``params["slots"]`` (one
+    ``(n_slots, ...)`` array per state field, read by the ``slot_type``
+    impl).
+
+    For every fragment (in the order of its ``slot_type`` node's id) the
+    stage passes ``fn`` the output arena and the fragment's output row, the
+    slot id (the ``slot_type`` node's ``aux``), the state arenas and rows
+    (``state_fields``, written by the node whose output the output node
+    reads) and the pools, and the program returns what ``fn`` returns:
+    ``(tokens, new_pools)``. A slot id past the pool writes nothing if
+    ``fn`` scatters with ``mode="drop"``. ``fn`` is part of the executable
+    key, so pass a module-level function."""
+
+    out_field: str
+    state_fields: tuple[str, ...]
+    slot_type: TypeId
+    fn: Callable
+
+
+@dataclass(frozen=True)
+class CommitLayout:
+    """Where one pack's commit stage reads: the output arena, the state
+    arenas, and the number of fragments. Fixed by the pack; part of the
+    executable key (the commit index vector's length is an operand
+    shape)."""
+
+    y_arena: ArenaKey
+    state_arenas: tuple[ArenaKey, ...]
+    n: int
+
+
 class BucketedPack:
     """One topology packed against its bucket: the runtime index operands
     plus the row table for result access. Cheap to build — no XLA.
 
     ``impls`` pins the impl dict for as long as the pack lives in a shared
     cache: cache keys namespace on ``id(impls)``, and an unpinned dict's id
-    could be recycled onto a different workload's impls after GC."""
+    could be recycled onto a different workload's impls after GC.
+
+    A pack built for a committing executor also carries its
+    :class:`CommitLayout` and ``commit_idx``: the fragments' slot lanes in
+    the aux vector, output rows, then state rows per field, each ``n``
+    long, device-resident like ``idxpack``."""
 
     def __init__(self, spec: BucketSpec, idxpack: jnp.ndarray,
                  aux_perm: np.ndarray, row_of: dict, stats: PlanStats,
@@ -743,6 +787,54 @@ class BucketedPack:
         self.row_of = row_of
         self.stats = stats
         self.impls = impls
+        self.commit_layout: CommitLayout | None = None
+        self.commit_idx: jnp.ndarray | None = None
+
+
+def _commit_pack(graph: Graph, pack: BucketedPack,
+                 impls: dict[TypeId, NodeImpl],
+                 commit: CommitSpec) -> tuple[CommitLayout, jnp.ndarray]:
+    """The commit stage's row vectors for ``pack``: one fragment per
+    ``slot_type`` node, traced forward to the node that consumes it and
+    writes every state field, then to that node's consumer that writes
+    ``out_field``."""
+    nodes = graph.nodes
+    consumers: dict[int, list[int]] = {}
+    for n in nodes:
+        for p in n.inputs:
+            consumers.setdefault(p, []).append(n.id)
+
+    def consumer_with(i: int, fields) -> int:
+        for c in consumers.get(i, ()):
+            if all(f in impls[nodes[c].type].out_fields for f in fields):
+                return c
+        raise ValueError(
+            f"node {i} ({nodes[i].type}) has no consumer writing "
+            f"{list(fields)}: the graph does not fit the commit stage")
+
+    lane_of: dict[int, int] = {}
+    for lane, i in enumerate(pack.aux_perm.tolist()):
+        lane_of.setdefault(i, lane)   # pad lanes repeat the last real id
+    slot_lanes, y_rows = [], []
+    state_rows: list[list[int]] = [[] for _ in commit.state_fields]
+    y_key = s_keys = None
+    for n in nodes:
+        if n.type != commit.slot_type:
+            continue
+        st = consumer_with(n.id, commit.state_fields)
+        out = consumer_with(st, (commit.out_field,))
+        y_key = (commit.out_field, tuple(
+            impls[nodes[out].type].out_fields[commit.out_field]))
+        s_keys = tuple((f, tuple(impls[nodes[st].type].out_fields[f]))
+                       for f in commit.state_fields)
+        slot_lanes.append(lane_of[n.id])
+        y_rows.append(pack.row_of[(y_key, out)])
+        for rows, k in zip(state_rows, s_keys):
+            rows.append(pack.row_of[(k, st)])
+    if y_key is None:
+        raise ValueError(f"no {commit.slot_type!r} node to commit")
+    vec = np.asarray(slot_lanes + y_rows + sum(state_rows, []), np.int32)
+    return CommitLayout(y_key, s_keys, len(slot_lanes)), jnp.asarray(vec)
 
 
 def _read_rows(opd: LoweredOperand, k: int) -> list[int]:
@@ -837,13 +929,18 @@ def pack_bucketed(low: Lowering, *, ladder: tuple[int, ...] | None = None,
 
 class _BucketProgram:
     """The traced shape-polymorphic program for one bucket signature: step
-    structure and widths are constants, every index vector is an operand."""
+    structure and widths are constants, every index vector is an operand.
+    ``commit``/``layout`` give :meth:`body_and_commit` its last stage."""
 
     def __init__(self, spec: BucketSpec, impls: dict[TypeId, NodeImpl], *,
                  gather_interpret: bool = False, fused: Any = "auto",
-                 fused_interpret: bool = False):
+                 fused_interpret: bool = False,
+                 commit: CommitSpec | None = None,
+                 layout: CommitLayout | None = None):
         self.spec = spec
         self.impls = impls
+        self.commit = commit
+        self.layout = layout
         self.gather_interpret = gather_interpret
         self.fused = fused
         self.fused_interpret = fused_interpret
@@ -893,6 +990,24 @@ class _BucketProgram:
                 arenas[key] = buf.at[oidx].set(val.astype(buf.dtype))
         return arenas
 
+    def body_and_commit(self, params: Any, idxpack: jnp.ndarray,
+                        aux_pack: jnp.ndarray,
+                        arenas: dict[ArenaKey, jnp.ndarray],
+                        commit_idx: jnp.ndarray) -> tuple:
+        """``body`` followed by the commit stage; returns ``(tokens,
+        new_pools, arenas)`` with ``new_pools`` keyed by state field."""
+        commit, layout = self.commit, self.layout
+        arenas = self.body(params, idxpack, aux_pack, arenas)
+        n = layout.n
+        parts = [jax.lax.slice_in_dim(commit_idx, i * n, (i + 1) * n)
+                 for i in range(2 + len(layout.state_arenas))]
+        pools = params["slots"]
+        toks, new_pools = commit.fn(
+            arenas[layout.y_arena], parts[1], aux_pack[parts[0]],
+            [arenas[k] for k in layout.state_arenas], parts[2:],
+            [pools[f] for f in commit.state_fields])
+        return toks, dict(zip(commit.state_fields, new_pools)), arenas
+
 
 class BucketedPlanExecutor:
     """Shape-polymorphic counterpart of :class:`PlanExecutor`.
@@ -902,6 +1017,10 @@ class BucketedPlanExecutor:
     executable is cached by *bucket signature* — typically a handful of
     entries serve an unbounded topology stream, so compile cost amortizes
     across every topology in the bucket instead of recurring per topology.
+
+    With ``commit`` (a :class:`CommitSpec`) every program ends in the
+    commit stage: :meth:`dispatch_packed`'s handle then also yields the
+    tokens and new state pools (:meth:`InFlightDispatch.tokens`).
     """
 
     def __init__(self, impls: dict[TypeId, NodeImpl], params: Any, *,
@@ -914,9 +1033,11 @@ class BucketedPlanExecutor:
                  pack_cache: FIFOCache | None = None,
                  exe_cache: FIFOCache | None = None, namespace: Any = None,
                  compile_hook: Callable[[Any], None] | None = None,
-                 tracer: Tracer | None = None):
+                 tracer: Tracer | None = None,
+                 commit: CommitSpec | None = None):
         self.impls = impls
         self.params = params
+        self.commit = commit
         self.layout = layout
         self.max_pq_vars = max_pq_vars
         self.pq_chunk = pq_chunk
@@ -945,8 +1066,11 @@ class BucketedPlanExecutor:
         # The effective ladder is part of the key: the async serve path
         # packs the same topology at coarser ladders to bridge onto an
         # already-compiled bucket while the native one is still building.
-        return ("pack", self._ns, graph.topology_key(),
-                policy_cache_key(policy), ladder)
+        # A committing pack carries its commit vectors, so it is kept
+        # apart from a plain pack of the same topology.
+        key = ("pack", self._ns, graph.topology_key(),
+               policy_cache_key(policy), ladder)
+        return key if self.commit is None else key + (self.commit,)
 
     def pack_for(self, graph: Graph,
                  policy: Policy | Callable[[Graph], Schedule],
@@ -968,6 +1092,9 @@ class BucketedPlanExecutor:
                 pack = pack_bucketed(low, ladder=lad,
                                      pad_steps=self.pad_steps,
                                      impls=self.impls)
+                if self.commit is not None:
+                    pack.commit_layout, pack.commit_idx = _commit_pack(
+                        graph, pack, self.impls, self.commit)
             pack.stats.lower_time_s = time.perf_counter() - t1
             self._packs[key] = pack
             if stats is not None:
@@ -986,7 +1113,10 @@ class BucketedPlanExecutor:
         return self._packs.peek(self._pack_key(graph, policy, lad))
 
     def executable_key(self, pack: BucketedPack, params: Any) -> tuple:
-        return (self._ns, pack.spec, _params_kind(params))
+        key = (self._ns, pack.spec, _params_kind(params))
+        if self.commit is None:
+            return key
+        return key + (self.commit, pack.commit_layout)
 
     def executable_ready(self, pack: BucketedPack, params: Any) -> bool:
         """True when the bucket executable is already in the shared cache —
@@ -1040,7 +1170,9 @@ class BucketedPlanExecutor:
             prog = _BucketProgram(pack.spec, self.impls,
                                   gather_interpret=self.gather_interpret,
                                   fused=self.fused,
-                                  fused_interpret=self.fused_interpret)
+                                  fused_interpret=self.fused_interpret,
+                                  commit=self.commit,
+                                  layout=pack.commit_layout)
             idx_spec = jax.ShapeDtypeStruct((pack.spec.n_index_lanes,),
                                             jnp.int32)
             aux_spec = jax.ShapeDtypeStruct((pack.spec.n_aux_lanes,),
@@ -1049,9 +1181,15 @@ class BucketedPlanExecutor:
                 lambda p, ix, ax: prog.body(p, ix, ax, {}),
                 params, idx_spec, aux_spec)
             pool = {k: jnp.zeros(s.shape, s.dtype) for k, s in shapes.items()}
-            jitted = jax.jit(prog.body,
-                             donate_argnums=(3,) if self.donate else ())
-            exe = jitted.lower(params, idx_spec, aux_spec, pool).compile()
+            donate = (3,) if self.donate else ()
+            if self.commit is None:
+                exe = jax.jit(prog.body, donate_argnums=donate).lower(
+                    params, idx_spec, aux_spec, pool).compile()
+            else:
+                exe = jax.jit(prog.body_and_commit,
+                              donate_argnums=donate).lower(
+                    params, idx_spec, aux_spec, pool,
+                    pack.commit_idx).compile()
             # The impls dict rides along to pin its id for the entry's
             # lifetime (the AOT executable itself holds no reference to it):
             # shared caches namespace on id(impls), which must not be
@@ -1088,12 +1226,13 @@ class BucketedPlanExecutor:
         bucket program is handed to the device (jax dispatch is async) and
         an :class:`InFlightDispatch` handle comes back immediately. The
         caller overlaps host work — the serve engine packs round t+1 here —
-        and calls ``handle.block()`` when it actually needs the arenas.
+        and calls ``handle.block()`` when it actually needs the arenas, or
+        ``handle.tokens()`` for a committing executor's tokens and pools.
 
-        Donation rotation and stat accounting are deferred to ``block()``:
-        until the caller commits, the cached executable entry still owns
-        the pre-dispatch pool, so a failed/abandoned round leaves the cache
-        coherent."""
+        Donation rotation and stat accounting are deferred to the first of
+        those: until the caller commits, the cached executable entry still
+        owns the pre-dispatch pool, so a failed/abandoned round leaves the
+        cache coherent."""
         stats = stats if stats is not None else ExecStats()
         tr = self.tracer
         params = params if params is not None else self.params
@@ -1109,9 +1248,12 @@ class BucketedPlanExecutor:
             key, entry, compile_s = self._ensure_executable(pack, params)
             exe, pool, impls_pin = entry
             t1 = time.perf_counter()
-            arenas = exe(params, pack.idxpack, aux, pool)
+            if self.commit is None:
+                out = (None, None, exe(params, pack.idxpack, aux, pool))
+            else:
+                out = exe(params, pack.idxpack, aux, pool, pack.commit_idx)
             dispatch_s = time.perf_counter() - t1
-        return InFlightDispatch(self, graph, pack, key, exe, arenas,
+        return InFlightDispatch(self, graph, pack, key, exe, out,
                                 impls_pin, stats, dispatch_s, compile_s)
 
 
@@ -1122,10 +1264,12 @@ class InFlightDispatch:
     exec stats (dispatch-call time + block-wait time — the overlap gap in
     between is *not* charged, so ``exec_s`` stays honest under pipelining)
     and returns the :class:`PlanResult`. Idempotent: repeated calls return
-    the same result."""
+    the same result. A committing program's run is read with ``tokens()``
+    instead, which waits for the tokens alone; ``in_program`` tells the
+    two apart."""
 
     def __init__(self, executor: BucketedPlanExecutor, graph: Graph,
-                 pack: BucketedPack, key: tuple, exe: Any, arenas: dict,
+                 pack: BucketedPack, key: tuple, exe: Any, out: tuple,
                  impls_pin: Any, stats: ExecStats, dispatch_s: float,
                  compile_s: float):
         self._ex = executor
@@ -1133,16 +1277,32 @@ class InFlightDispatch:
         self._pack = pack
         self._key = key
         self._exe = exe
-        self._arenas = arenas
+        self._toks, self._pools, self._arenas = out
         self._impls_pin = impls_pin
         self._stats = stats
         self._dispatch_s = dispatch_s
         self._compile_s = compile_s
         self._result: PlanResult | None = None
+        self._settled = False
 
     @property
     def pending(self) -> bool:
-        return self._result is None
+        return not self._settled
+
+    @property
+    def in_program(self) -> bool:
+        """True when the program ran the executor's commit stage."""
+        return self._toks is not None
+
+    def tokens(self) -> tuple[np.ndarray, dict]:
+        """Wait for the commit stage's tokens and read them to the host:
+        ``(tokens for every fragment, new state pools by field)``. The
+        pools stay on the device."""
+        t0 = time.perf_counter()
+        with self._ex.tracer.span("plan.block", cat="plan"):
+            toks = np.asarray(self._toks)
+        self._settle(time.perf_counter() - t0)
+        return toks, self._pools
 
     def block(self) -> PlanResult:
         if self._result is not None:
@@ -1151,7 +1311,17 @@ class InFlightDispatch:
         t0 = time.perf_counter()
         with ex.tracer.span("plan.block", cat="plan"):
             jax.block_until_ready(list(self._arenas.values()))
-        wait_s = time.perf_counter() - t0
+        self._settle(time.perf_counter() - t0)
+        self._result = PlanResult(self._graph, ex.impls, self._arenas,
+                                  self._pack.row_of)
+        return self._result
+
+    def _settle(self, wait_s: float) -> None:
+        """Once per run: rotate the donation pool and book the stats."""
+        if self._settled:
+            return
+        self._settled = True
+        ex = self._ex
         if ex.donate:
             ex._exes[self._key] = (self._exe, self._arenas, self._impls_pin)
         st = self._stats
@@ -1163,9 +1333,6 @@ class InFlightDispatch:
         st.exec_time += self._dispatch_s + wait_s
         st.n_batches += self._pack.stats.n_steps
         st.n_launches += 1
-        self._result = PlanResult(self._graph, ex.impls, self._arenas,
-                                  self._pack.row_of)
-        return self._result
 
 
 # ---------------------------------------------------------------------------

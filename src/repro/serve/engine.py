@@ -64,7 +64,7 @@ import numpy as np
 from repro.core.batching import SufficientConditionPolicy, policy_cache_key
 from repro.core.cache import FIFOCache, LRUCache
 from repro.core.executor import DynamicExecutor, ExecStats
-from repro.core.plan import (BucketedPlanExecutor, PlanExecutor,
+from repro.core.plan import (BucketedPlanExecutor, CommitSpec, PlanExecutor,
                              ShardedBucketedPlanExecutor, _sig_digest)
 from repro.models.workloads import SERVE_FAMILIES, make_workload
 from repro.obs import FlightRecorder, Obs, Tracer
@@ -143,6 +143,9 @@ class ServeStats:
     n_pipelined_rounds: int = 0
     n_overlapped_packs: int = 0
     n_spec_cancelled: int = 0
+    # lm rounds whose commit (token argmax, state scatter) ran as the last
+    # stage of the round's bucket program (DESIGN.md §9).
+    n_commit_in_program: int = 0
     # Sharded single-shot rounds whose diverging shard specs were padded
     # back onto one shared bucket signature (spec-aligned merging) instead
     # of degrading to per-shard dispatch.
@@ -160,7 +163,8 @@ class ServeStats:
                "compile_jobs_landed", "compile_jobs_retried",
                "compile_jobs_timed_out", "compile_jobs_quarantined",
                "n_pipelined_rounds", "n_overlapped_packs",
-               "n_spec_cancelled", "n_merge_aligned_rounds")
+               "n_spec_cancelled", "n_merge_aligned_rounds",
+               "n_commit_in_program")
     # Shards serve the same rounds concurrently, so wall-clock style fields
     # take the max across parts (like n_rounds), never the sum — summing
     # would inflate them K-fold and understate tok_per_s.
@@ -223,15 +227,25 @@ def _fused_zero(slots, pools):
 
 @jax.jit
 def _fused_commit(y_arena, y_rows, slots, state_arenas, state_rows, pools):
-    """Single-dispatch lm round commit: argmax the entries' output rows
-    into next tokens and scatter their recurrent state back into the slot
-    pools. Module-level so the jit cache is shared by every engine in the
-    process; retraces only per live-entry count (bounded by ``max_slots``).
-    Pools are not donated — a checkpoint may still hold the old arrays."""
+    """The lm round commit: argmax the entries' output rows into next
+    tokens and scatter their recurrent state back into the slot pools. A
+    slot id past the pool (a dummy fragment's) writes nothing.
+    Module-level so the jit cache is shared by every engine in the
+    process; as a dispatch of its own it retraces only per live-entry
+    count (bounded by ``max_slots``). Pools are not donated — a checkpoint
+    may still hold the old arrays."""
     toks = jnp.argmax(y_arena[y_rows], axis=-1)
-    new_pools = [p.at[slots].set(a[r])
+    new_pools = [p.at[slots].set(a[r], mode="drop")
                  for p, a, r in zip(pools, state_arenas, state_rows)]
     return toks, new_pools
+
+
+def _commit_stage(y_arena, y_rows, slots, state_arenas, state_rows, pools):
+    """The bucket program's commit stage (``CommitSpec.fn``): the
+    module's ``_fused_commit``, looked up when the program is traced, so
+    the in-program, eager and sharded commits share one definition."""
+    return _fused_commit(y_arena, y_rows, slots, state_arenas, state_rows,
+                         pools)
 
 
 class _ReadyRound:
@@ -240,6 +254,7 @@ class _ReadyRound:
     Lets the pipelined commit path treat every tier uniformly."""
 
     pending = False
+    in_program = False
 
     def __init__(self, result):
         self._result = result
@@ -489,13 +504,19 @@ class ServeEngine:
                     exe_cache=self.bucket_cache, namespace=ns,
                     compile_hook=hook, tracer=self.tracer)
             elif self.compiled and self.bucketed:
+                # A family that keeps recurrent state (lm) commits inside
+                # its round program: tokens and new slot pools come out of
+                # the one dispatch (DESIGN.md §9).
+                fields = getattr(wl, "state_fields", None)
+                commit = (CommitSpec("y", tuple(fields), "R", _commit_stage)
+                          if fields else None)
                 ex = BucketedPlanExecutor(wl.impls, None, layout=self.layout,
                                           donate=self.donate,
                                           ladder=self.bucket_ladder,
                                           pack_cache=self.plan_cache,
                                           exe_cache=self.bucket_cache,
                                           namespace=ns, compile_hook=hook,
-                                          tracer=self.tracer)
+                                          tracer=self.tracer, commit=commit)
             elif self.compiled:
                 ex = PlanExecutor(wl.impls, None, layout=self.layout,
                                   donate=self.donate, cache=self.plan_cache,
@@ -903,12 +924,10 @@ class ServeEngine:
 
     # -- the degradation ladder ----------------------------------------------
 
-    def _exec_graph(self, fam: str, graph, params: Any = None,
-                    coarse_fn=None):
+    def _exec_graph(self, fam: str, graph, params: Any = None):
         """Run one round graph down the degradation ladder; returns
-        ``(result, tier)``. ``coarse_fn(count)`` (lm feed rounds only)
-        rebuilds the round graph padded to a coarser count bucket — the
-        async path's bridge tier while the native build is in flight.
+        ``(result, tier)``. (A bucketed lm feed round takes
+        ``_dispatch_lm`` instead, which also has the coarse bridge.)
 
         The primary tier (bucketed / per-topology plan) is skipped while
         its quarantine key — the bucket signature on the bucketed path, the
@@ -923,8 +942,7 @@ class ServeEngine:
         es = self._exec_stats[fam]
         tier = self._primary_tier()
         if tier == "bucketed" and self._compiler is not None:
-            return self._exec_graph_async(fam, ex, pol, es, graph, params,
-                                          coarse_fn)
+            return self._exec_graph_async(fam, ex, pol, es, graph, params)
         if tier != "interpreted":
             qkey = None
             try:
@@ -952,14 +970,13 @@ class ServeEngine:
     # -- async tier selection (DESIGN.md §8) ----------------------------------
 
     def _exec_graph_async(self, fam: str, ex, pol, es, graph,
-                          params: Any = None, coarse_fn=None):
+                          params: Any = None):
         """Non-blocking counterpart of the primary-tier branch: the serve
         loop only *probes* caches — every piece of lowering (schedule,
         pack, XLA build) runs on the compile service's workers. Ready
         native bucket -> ``bucketed``; not ready -> submit the build and
-        bridge through a coarser already-compiled bucket (``coarse``), else
-        the interpreted floor. The first compiled round after degraded ones
-        is the hot-swap."""
+        serve the interpreted floor. The first compiled round after
+        degraded ones is the hot-swap."""
         jobsig, pack, blocked, ready = self._lookup(fam, ex, pol, graph,
                                                     params)
         if ready:
@@ -984,10 +1001,6 @@ class ServeEngine:
             # hot-swap (submission itself dedupes inside the service).
             self._submit_compile_job(fam, ex, pol, graph, jobsig, params)
             self._awaiting.add(jobsig)
-            cres = self._try_coarse(fam, ex, pol, es, graph, params,
-                                    coarse_fn)
-            if cres is not None:
-                return cres, "coarse"
         res = self._interp_executor(fam).run(graph, pol, es, params=params)
         return res, "interpreted"
 
@@ -1018,8 +1031,6 @@ class ServeEngine:
         graph construction is host-side microseconds, and a pack that was
         never built (that count bucket never ran) is simply a miss — no
         lowering happens here."""
-        if coarse_fn is None:
-            return None
         count = len(graph) // 4
         for mult in (2, 4):
             cg = coarse_fn(count * mult)
@@ -1450,24 +1461,27 @@ class ServeEngine:
             return None
         return _ReadyRound(res), "interpreted", None, None
 
-    def _run_lm_round_pipelined(self, plan, wl, pool, graph, entries,
-                                coarse_fn):
-        """Two-stage round: dispatch round t without blocking, overlap the
-        host-side plan+pack of round t+1 with the in-flight device work,
-        then commit — block on t's arenas, scatter, feed. A commit failure
-        (device error surfacing at block, or an injected commit fault)
+    def _run_lm_feed_round(self, plan, wl, pool, graph, entries,
+                           coarse_fn):
+        """One bucketed lm feed round: dispatch round t without blocking,
+        overlap the host-side plan+pack of round t+1 with the in-flight
+        device work (``pipeline``), then commit. On the bucketed tier the
+        commit ran inside the round's program: one read of the tokens, and
+        the new slot pools taken as they are. The coarse bridge and the
+        floor commit on the host (``_scatter_commit``). A commit failure
+        (device error surfacing at the read, or an injected commit fault)
         cancels the speculation *first*, so the containment ladder and the
-        re-planned round t+1 both see rolled-back state. Returns the
-        round's result (``None`` after isolation), which the caller
-        releases with the round graph."""
-        with self.tracer.span("round.dispatch"):
+        re-planned round t+1 both see rolled-back state. Returns what the
+        caller releases with the round graph (``None`` after isolation)."""
+        tr = self.tracer
+        with tr.span("round.dispatch"):
             rd = self._dispatch_lm(graph, pool, coarse_fn)
         if rd is None:
             self._contained()
             return self._isolate_lm_round(plan, wl, True)
         handle, tier, qkey, jobsig = rd
         if self.pipeline and handle.pending:
-            with self.tracer.span("round.speculate"):
+            with tr.span("round.speculate"):
                 self._speculate_next(plan, entries)
         try:
             if self._injector is not None:
@@ -1480,10 +1494,22 @@ class ServeEngine:
             self._contained()
             return self._isolate_lm_round(plan, wl, True)
         try:
-            with self.tracer.span("round.settle"):
-                res = handle.block()
-                if qkey is not None:
-                    self.quarantine.clear(qkey)
+            if handle.in_program:
+                with tr.span("round.scatter"):
+                    with tr.span("round.readback"):
+                        toks, new_pools = handle.tokens()
+                    with tr.span("round.commit", in_program=True):
+                        self.quarantine.clear(qkey)
+                        pool.update(new_pools)
+                        self.stats.n_commit_in_program += 1
+                        self._metrics.counter(
+                            "serve.lm.commit_in_program").inc()
+                res = handle
+            else:
+                with tr.span("round.settle"):
+                    res = handle.block()
+                    if qkey is not None:
+                        self.quarantine.clear(qkey)
         except Exception as exc:
             self._cancel_spec()
             if qkey is not None:
@@ -1492,13 +1518,15 @@ class ServeEngine:
             return self._isolate_lm_round(plan, wl, True)
         self._note_tier(tier)
         self._note_hotswap(jobsig, "lm")
-        if tier == "bucketed":
+        if tier == "bucketed" and self.pipeline:
             self.stats.n_pipelined_rounds += 1
-        with self.tracer.span("round.scatter"):
-            toks = self._scatter_commit(res, entries, wl, pool)
-        with self.tracer.span("round.feed"):
-            self._feed_tokens(entries, toks, time.perf_counter(),
-                              self._shard_stats[0])
+        if not handle.in_program:
+            with tr.span("round.scatter"):
+                toks = self._scatter_commit(res, entries, wl, pool)
+        with tr.span("round.feed"):
+            # Live entries lead the round graph; the dummies' tokens trail.
+            self._feed_tokens(entries, toks[:len(entries)],
+                              time.perf_counter(), self._shard_stats[0])
         return res
 
     def _scatter_commit(self, res, entries, wl, pool):
@@ -1651,25 +1679,22 @@ class ServeEngine:
                                if e.req is not None]
         if graph is None:
             return
-        coarse_fn = None
-        if feed_mode and self._compiler is not None:
+        if feed_mode:
             # Bridge-tier rebuild: the same plan padded to a coarser count
             # bucket (real entries keep their node ids, dummies append), so
-            # the scatter below reads the same o/cell nodes either way.
+            # the commit reads the same o/cell nodes either way.
             def coarse_fn(count):
                 return build_lm_feed_round_graph(plan, count=count)[0]
-        if self.pipeline and feed_mode:
-            res = self._run_lm_round_pipelined(plan, wl, pool, graph,
-                                               entries, coarse_fn)
+
+            res = self._run_lm_feed_round(plan, wl, pool, graph, entries,
+                                          coarse_fn)
         else:
             try:
                 res, tier = self._exec_graph("lm", graph,
-                                             params={"slots": pool},
-                                             coarse_fn=coarse_fn)
+                                             params={"slots": pool})
                 if self._injector is not None:
-                    # Commit-fault parity with the pipelined path: the
-                    # serial loop's commit boundary sits right after
-                    # execution.
+                    # Commit-fault parity with the feed round: the commit
+                    # boundary sits right after execution.
                     self._injector.on_commit(self._round)
             except Exception:
                 # Even the interpreted floor failed on the merged graph:

@@ -53,6 +53,10 @@ SINGLE_SHOT_FAMILIES = ("tree", "lattice")
 # engine's sharded path must pad every shard to the same rung, so it shares
 # this constant with build_lm_feed_round_graph's default.
 COUNT_BUCKET_MIN = 8
+# The slot id of a dummy fragment in an lm feed round: past every slot
+# pool, so its resume read clamps onto a real row and the commit's
+# scatter drops its write — a dummy never changes a slot.
+DUMMY_SLOT = 2**31 - 1
 
 
 def bucket_len(n: int, min_bucket: int = 4,
@@ -339,8 +343,9 @@ def build_lm_feed_round_graph(plan: RoundPlan, *, pad_token: int = 0,
     nothing but the padded entry count, so with the serve width ladder the
     whole lm lifetime — any prompt-length mix, any decode phase — runs
     through one or two bucketed executables. Entry count pads to
-    ``count_bucket_min`` with dummy fragments (slot 0, token 0, writeback
-    discarded), which also keeps the per-topology pack cache tiny.
+    ``count_bucket_min`` with dummy fragments (slot ``DUMMY_SLOT``, token
+    0, never written back), which also keeps the per-topology pack cache
+    tiny. Live entries come first, in the order returned.
 
     ``count`` overrides the padded entry count: the sharded engine passes
     the max bucket across shards so every shard's round graph — including
@@ -353,7 +358,8 @@ def build_lm_feed_round_graph(plan: RoundPlan, *, pad_token: int = 0,
         count = bucket_len(len(live), count_bucket_min)
     elif count < len(live):
         raise ValueError(f"count={count} < {len(live)} live entries")
-    entries = live + [LMEntry(None, 0) for _ in range(count - len(live))]
+    entries = live + [LMEntry(None, DUMMY_SLOT)
+                      for _ in range(count - len(live))]
     nodes: list[Node] = []
 
     def add(type_, inputs=(), aux=0):

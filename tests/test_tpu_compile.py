@@ -16,7 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.batching import SufficientConditionPolicy
-from repro.core.plan import BucketedPlanExecutor, _BucketProgram
+from repro.core.plan import BucketedPlanExecutor, CommitSpec, _BucketProgram
 from repro.kernels.fused_gather_cell import fused_gather_lstm_cell_kernel
 from repro.kernels.gather_batch import gather_rows_kernel
 from repro.models.workloads import make_workload
@@ -98,3 +98,35 @@ def test_lm_bucket_program_compiles_with_kernels(one_chip, monkeypatch):
             for k, s in shapes.items()}
     compiled = jax.jit(prog.body).lower(params, idx, aux, pool).compile()
     assert compiled.as_text().count("tpu_custom_call") > 0
+
+
+def test_lm_committing_bucket_program_compiles_for_v5e(one_chip):
+    """The serve engine's committing lm program at the benchmark cell's
+    widths (hidden 650, vocab 10000, 64 entries): the round's commit stage
+    (argmax, slot-pool scatter) compiles inside the bucket program."""
+    from repro.models.chains import ChainLM
+    from repro.serve.engine import _commit_stage
+
+    wl = ChainLM(650, 1409, vocab=10000)
+    graph, _ = build_lm_feed_round_graph(RoundPlan(), count=64)
+    commit = CommitSpec("y", tuple(wl.state_fields), "R", _commit_stage)
+    pack = BucketedPlanExecutor(wl.impls, None, ladder=(8,),
+                                commit=commit).pack_for(
+        graph, SufficientConditionPolicy())
+    prog = _BucketProgram(pack.spec, wl.impls, commit=commit,
+                          layout=pack.commit_layout)
+    params = {"slots": {f: _f32((64, 650), one_chip)
+                        for f in wl.state_fields}}
+    idx = _i32((pack.spec.n_index_lanes,), one_chip)
+    aux = _i32((pack.spec.n_aux_lanes,), one_chip)
+    shapes = jax.eval_shape(lambda p, i, a: prog.body(p, i, a, {}),
+                            params, idx, aux)
+    pool = {k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+            for k, s in shapes.items()}
+    cidx = _i32(pack.commit_idx.shape, one_chip)
+    compiled = jax.jit(prog.body_and_commit).lower(params, idx, aux, pool,
+                                                   cidx).compile()
+    toks, new_pools, _ = compiled.out_info
+    assert toks.shape == (64,)
+    assert {f: s.shape for f, s in new_pools.items()} == {
+        f: (64, 650) for f in wl.state_fields}
