@@ -39,22 +39,30 @@ class Graph:
 
     def __init__(self, nodes: Sequence[Node]):
         self.nodes: list[Node] = list(nodes)
-        n = len(self.nodes)
         for i, node in enumerate(self.nodes):
             if node.id != i:
                 raise ValueError(f"node ids must be dense 0..n-1, got {node.id} at {i}")
             for p in node.inputs:
                 if not (0 <= p < i):
                     raise ValueError(f"node {i} has non-topological input {p}")
-        self.succs: list[list[int]] = [[] for _ in range(n)]
-        for node in self.nodes:
-            for p in node.inputs:
-                self.succs[p].append(node.id)
         self.types: list[TypeId] = sorted({nd.type for nd in self.nodes}, key=repr)
         self._depth: list[int] | None = None
+        self._succs: list[list[int]] | None = None
 
     def __len__(self) -> int:
         return len(self.nodes)
+
+    @property
+    def succs(self) -> list[list[int]]:
+        """Successor ids per node, built on first use: a request graph that
+        is only merged into a wave graph never schedules, and a server
+        keeps every request it served."""
+        if self._succs is None:
+            self._succs = [[] for _ in self.nodes]
+            for node in self.nodes:
+                for p in node.inputs:
+                    self._succs[p].append(node.id)
+        return self._succs
 
     @property
     def depth(self) -> list[int]:

@@ -177,6 +177,7 @@ class PlanStats:
     pq_skipped: str = ""              # non-empty: PQ pipeline skipped (+ why)
     bucketed: bool = False            # lowered for the bucketed executor
     n_pad_steps: int = 0              # inactive steps added by run padding
+    n_real_lanes: int = 0             # bucketed: lanes that carry a real node
     n_compiles: int = 0               # XLA compiles charged to this plan
     lower_time_s: float = 0.0
     compile_time_s: float = 0.0
@@ -856,6 +857,11 @@ def pack_bucketed(low: Lowering, *, ladder: tuple[int, ...] | None = None,
     - pad *lanes* replicate the last real lane on reads and target the
       arena's reserved trash row (the last padded row, never a real row) on
       writes;
+    - every step of a run of consecutive same-type steps takes one width,
+      the bucket of the run's widest step, so the spec depends only on the
+      run's length and its widest step, not on how the width falls off
+      along the run (a wave of fresh trees has a new depth profile every
+      time); a run of one step pads as it always did;
     - pad *steps* (run-length padding of consecutive same-type steps)
       re-execute the run's last real step with all-trash writes, so a chain
       of 11 cells and a chain of 13 share the 16-step program.
@@ -869,8 +875,7 @@ def pack_bucketed(low: Lowering, *, ladder: tuple[int, ...] | None = None,
     aux_perm: list[int] = []
     n_pad = 0
 
-    def emit(step: LoweredStep, pad: bool) -> None:
-        wp = bucket_up(step.k, ladder)
+    def emit(step: LoweredStep, wp: int, pad: bool) -> None:
         in_keys = []
         in_idx = []
         for opd in step.inputs:
@@ -904,14 +909,15 @@ def pack_bucketed(low: Lowering, *, ladder: tuple[int, ...] | None = None,
         while j < len(low.steps) and low.steps[j].type == low.steps[i].type:
             j += 1
         run = low.steps[i:j]
+        wp = bucket_up(max(s.k for s in run), ladder)
         for s in run:
-            emit(s, pad=False)
+            emit(s, wp, pad=False)
         if pad_steps:
             # Run lengths pad on the pure power-of-two ladder: a width
             # ladder's floor exists to merge small *batches*, and applying
             # it here would multiply every short run into `floor` steps.
             for _ in range(bucket_up(len(run)) - len(run)):
-                emit(run[-1], pad=True)
+                emit(run[-1], wp, pad=True)
                 n_pad += 1
         i = j
 
@@ -920,6 +926,7 @@ def pack_bucketed(low: Lowering, *, ladder: tuple[int, ...] | None = None,
     stats = low.stats
     stats.bucketed = True
     stats.n_pad_steps = n_pad
+    stats.n_real_lanes = sum(s.k for s in low.steps)
     idxpack = (np.concatenate(idx_parts) if idx_parts
                else np.zeros(0, np.int32))
     return BucketedPack(spec, jnp.asarray(idxpack),
@@ -1062,22 +1069,35 @@ class BucketedPlanExecutor:
 
     def _pack_key(self, graph: Graph,
                   policy: Policy | Callable[[Graph], Schedule],
-                  ladder: tuple[int, ...] | None) -> tuple:
+                  ladder: tuple[int, ...] | None, layout: str) -> tuple:
         # The effective ladder is part of the key: the async serve path
         # packs the same topology at coarser ladders to bridge onto an
         # already-compiled bucket while the native one is still building.
-        # A committing pack carries its commit vectors, so it is kept
-        # apart from a plain pack of the same topology.
+        # A committing pack carries its commit vectors, and a pack laid
+        # out other than the executor's own layout carries that layout, so
+        # each is kept apart from a plain pack of the same topology.
         key = ("pack", self._ns, graph.topology_key(),
                policy_cache_key(policy), ladder)
+        if layout != self.layout:
+            key += (layout,)
         return key if self.commit is None else key + (self.commit,)
 
     def pack_for(self, graph: Graph,
                  policy: Policy | Callable[[Graph], Schedule],
                  stats: ExecStats | None = None,
-                 ladder: tuple[int, ...] | None = None) -> BucketedPack:
+                 ladder: tuple[int, ...] | None = None,
+                 layout: str | None = None) -> BucketedPack:
+        """The bucketed pack of ``graph`` under ``policy``, cached.
+
+        ``layout`` overrides the executor's row layout for this pack. The
+        bucket spec does not depend on it: the program reads every operand
+        through its index vector, so only the index values differ. A pack
+        in declaration order (``"schedule"``) lowers in time linear in the
+        graph, where the PQ-tree plan can take minutes for a few hundred
+        layout variables, joint or chunked."""
         lad = self.ladder if ladder is None else tuple(ladder)
-        key = self._pack_key(graph, policy, lad)
+        lay = self.layout if layout is None else layout
+        key = self._pack_key(graph, policy, lad, lay)
         pack = self._packs.get(key)
         if pack is None:
             t0 = time.perf_counter()
@@ -1086,7 +1106,7 @@ class BucketedPlanExecutor:
             t1 = time.perf_counter()
             with self.tracer.span("plan.lower", cat="plan"):
                 low = lower_schedule(graph, sched, self.impls,
-                                     layout=self.layout,
+                                     layout=lay,
                                      max_pq_vars=self.max_pq_vars,
                                      pq_chunk=self.pq_chunk)
                 pack = pack_bucketed(low, ladder=lad,
@@ -1104,13 +1124,14 @@ class BucketedPlanExecutor:
 
     def pack_ready(self, graph: Graph,
                    policy: Policy | Callable[[Graph], Schedule],
-                   ladder: tuple[int, ...] | None = None
-                   ) -> BucketedPack | None:
-        """Cached pack for ``(graph, policy, ladder)`` or ``None`` — a pure
-        probe: no lowering, no hit/miss accounting. The async serve loop
-        uses this each round so host-side lowering stays off the loop."""
+                   ladder: tuple[int, ...] | None = None,
+                   layout: str | None = None) -> BucketedPack | None:
+        """Cached pack for ``(graph, policy, ladder, layout)`` or ``None`` —
+        a pure probe: no lowering, no hit/miss accounting. The async serve
+        loop uses this each round so host-side lowering stays off the loop."""
         lad = self.ladder if ladder is None else tuple(ladder)
-        return self._packs.peek(self._pack_key(graph, policy, lad))
+        lay = self.layout if layout is None else layout
+        return self._packs.peek(self._pack_key(graph, policy, lad, lay))
 
     def executable_key(self, pack: BucketedPack, params: Any) -> tuple:
         key = (self._ns, pack.spec, _params_kind(params))

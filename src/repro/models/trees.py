@@ -24,8 +24,8 @@ N_CLASSES = 5
 VOCAB = 1000
 
 
-def _tree_graph(trees: list[TreeNode], internal_types: int = 1,
-                with_c: bool = True) -> Graph:
+def tree_graph(trees: list[TreeNode], internal_types: int = 1,
+               with_c: bool = True) -> Graph:
     nodes: list[Node] = []
 
     def add(type_, inputs=(), aux=0):
@@ -50,30 +50,46 @@ def _tree_graph(trees: list[TreeNode], internal_types: int = 1,
     return Graph(nodes)
 
 
-def _out_impl(rng: np.random.Generator, hidden: int) -> NodeImpl:
-    w = jnp.asarray(0.1 * rng.standard_normal((hidden, N_CLASSES)), jnp.float32)
-    b = jnp.zeros(N_CLASSES, jnp.float32)
+def _out_impl(rng: np.random.Generator, hidden: int,
+              n_classes: int = N_CLASSES) -> NodeImpl:
+    w = jnp.asarray(0.1 * rng.standard_normal((hidden, n_classes)), jnp.float32)
+    b = jnp.zeros(n_classes, jnp.float32)
 
     def apply(params, inputs, aux):
         return {"y": jnp.matmul(inputs[0], w, precision=MATMUL_PRECISION) + b}
 
-    return NodeImpl("O", [(0, "h")], {"y": (N_CLASSES,)}, apply)
+    return NodeImpl("O", [(0, "h")], {"y": (n_classes,)}, apply)
 
 
 class TreeWorkload:
-    """name in {TreeLSTM, TreeGRU, MV-RNN, TreeLSTM-2Type}."""
+    """name in {TreeLSTM, TreeGRU, MV-RNN, TreeLSTM-2Type}.
+
+    ``model_size`` is the hidden (memory) width; ``embed``, ``vocab`` and
+    ``n_classes`` default to ``model_size``, ``VOCAB`` and ``N_CLASSES``.
+    A TreeLSTM's weights are drawn from ``default_rng(seed)`` as 0.1 times
+    a standard normal, in this order: the embedding ``(vocab, embed)``;
+    the leaf cell's ``W_i, b_i, W_o, b_o, W_g, b_g`` (``(embed, hidden)``
+    and ``(hidden,)``); each internal cell's ``W_i, b_i, W_fl, b_fl, W_fr,
+    b_fr, W_o, b_o, W_g, b_g`` (``(2 hidden, hidden)`` and ``(hidden,)``,
+    the input being ``[h_left; h_right]``); the head's ``(hidden,
+    n_classes)``. The head's bias is zero."""
 
     def __init__(self, name: str, model_size: int = 64, seed: int = 0,
-                 layout: str = "planned"):
+                 layout: str = "planned", *, embed: int | None = None,
+                 vocab: int = VOCAB, n_classes: int = N_CLASSES):
         self.name = name
         self.model_size = model_size
         self.layout = layout
+        self.vocab = vocab
         rng = np.random.default_rng(seed)
         h = model_size
+        e = h if embed is None else embed
+        if e != h and name not in ("TreeLSTM", "TreeLSTM-2Type"):
+            raise ValueError(f"{name} takes embed = hidden")
         self.impls: dict = {}
         if name in ("TreeLSTM", "TreeLSTM-2Type"):
-            leaf = CompiledCell(treelstm_leaf(h, h), layout)
-            table = jnp.asarray(0.1 * rng.standard_normal((VOCAB, h)), jnp.float32)
+            leaf = CompiledCell(treelstm_leaf(e, h), layout)
+            table = jnp.asarray(0.1 * rng.standard_normal((vocab, e)), jnp.float32)
             self.impls["E"] = embed_impl("E", table, "x")
             self.impls["L"] = cell_impl("L", leaf, [(0, "x")], ["x"],
                                         leaf.init_params(rng))
@@ -89,7 +105,7 @@ class TreeWorkload:
         elif name == "TreeGRU":
             leaf = CompiledCell(treegru_leaf(h, h), layout)
             internal = CompiledCell(treegru_internal(h), layout)
-            table = jnp.asarray(0.1 * rng.standard_normal((VOCAB, h)), jnp.float32)
+            table = jnp.asarray(0.1 * rng.standard_normal((vocab, h)), jnp.float32)
             self.impls["E"] = embed_impl("E", table, "x")
             self.impls["L"] = cell_impl("L", leaf, [(0, "x")], ["x"],
                                         leaf.init_params(rng))
@@ -100,10 +116,10 @@ class TreeWorkload:
             self.cells = {"TreeGRU-Leaf": leaf, "TreeGRU-Internal": internal}
         elif name == "MV-RNN":
             internal = CompiledCell(mv_cell(h), layout)
-            vec = jnp.asarray(0.1 * rng.standard_normal((VOCAB, h)), jnp.float32)
+            vec = jnp.asarray(0.1 * rng.standard_normal((vocab, h)), jnp.float32)
             mat = jnp.asarray(
-                np.broadcast_to(np.eye(h, dtype=np.float32), (VOCAB, h, h))
-                + 0.02 * rng.standard_normal((VOCAB, h, h)), jnp.float32)
+                np.broadcast_to(np.eye(h, dtype=np.float32), (vocab, h, h))
+                + 0.02 * rng.standard_normal((vocab, h, h)), jnp.float32)
 
             def embed_apply(params, inputs, aux):
                 return {"a_out": vec[aux], "A_out": mat[aux]}
@@ -121,7 +137,7 @@ class TreeWorkload:
         else:
             raise ValueError(name)
         # Output head reads the h-like field.
-        out = _out_impl(rng, h)
+        out = _out_impl(rng, h, n_classes)
         out.in_slots = [(0, self._h_field)]
         self.impls["O"] = out
         self.impls = {k: v for k, v in self.impls.items() if v is not None}
@@ -130,10 +146,10 @@ class TreeWorkload:
                      leaves_lo: int = 6, leaves_hi: int = 18) -> Graph:
         n_tags = 2 if self.name == "TreeLSTM-2Type" else 1
         trees = [random_tree(rng, rng.randint(leaves_lo, leaves_hi),
-                             VOCAB, n_tags) for _ in range(batch_size)]
+                             self.vocab, n_tags) for _ in range(batch_size)]
         if self.name == "MV-RNN":
             return _mvrnn_graph(trees)
-        return _tree_graph(trees, internal_types=n_tags)
+        return tree_graph(trees, internal_types=n_tags)
 
 
 def _mvrnn_graph(trees: list[TreeNode]) -> Graph:

@@ -82,6 +82,12 @@ from .scheduler import (COUNT_BUCKET_MIN, ContinuousScheduler, RoundPlan,
                         merge_request_graphs, next_feed_token,
                         partition_singles)
 
+# Row layout of the single-shot packs made on the serve loop: declaration
+# order, which lowers in time linear in the wave. The bucket spec and its
+# executable do not depend on the layout, and the PQ-tree plan of a wave
+# of a few hundred layout variables can hold the loop for minutes.
+_LOOP_LAYOUT = "schedule"
+
 
 @dataclass
 class ServeStats:
@@ -150,6 +156,14 @@ class ServeStats:
     # back onto one shared bucket signature (spec-aligned merging) instead
     # of degrading to per-shard dispatch.
     n_merge_aligned_rounds: int = 0
+    # Single-shot rounds under async compile (DESIGN.md §8): waves packed
+    # on the loop because no pack was cached, rounds served on the
+    # interpreted floor, and the lanes of the dispatched bucket specs'
+    # steps that carry a real node or padding.
+    n_single_fresh_pack: int = 0
+    n_single_floor: int = 0
+    n_single_lanes_real: int = 0
+    n_single_lanes_padded: int = 0
 
     _SUMMED = ("n_batches", "n_launches", "n_compiles", "tokens_out",
                "outputs_out", "requests_done", "plan_cache_hits",
@@ -164,7 +178,9 @@ class ServeStats:
                "compile_jobs_timed_out", "compile_jobs_quarantined",
                "n_pipelined_rounds", "n_overlapped_packs",
                "n_spec_cancelled", "n_merge_aligned_rounds",
-               "n_commit_in_program")
+               "n_commit_in_program", "n_single_fresh_pack",
+               "n_single_floor", "n_single_lanes_real",
+               "n_single_lanes_padded")
     # Shards serve the same rounds concurrently, so wall-clock style fields
     # take the max across parts (like n_rounds), never the sum — summing
     # would inflate them K-fold and understate tok_per_s.
@@ -971,16 +987,28 @@ class ServeEngine:
 
     def _exec_graph_async(self, fam: str, ex, pol, es, graph,
                           params: Any = None):
-        """Non-blocking counterpart of the primary-tier branch: the serve
-        loop only *probes* caches — every piece of lowering (schedule,
-        pack, XLA build) runs on the compile service's workers. Ready
-        native bucket -> ``bucketed``; not ready -> submit the build and
-        serve the interpreted floor. The first compiled round after
-        degraded ones is the hot-swap."""
-        jobsig, pack, blocked, ready = self._lookup(fam, ex, pol, graph,
-                                                    params)
-        if ready:
-            qkey = (fam, pack.spec)
+        """Non-blocking counterpart of the primary-tier branch for a
+        single-shot wave: no XLA build runs on the loop. A wave never
+        packed before is packed here (schedule, rows in declaration order
+        and index packing: host work linear in the wave, never the PQ-tree
+        plan) so that its bucket spec can be probed;
+        waves of fresh trees share a few specs (run-uniform widths,
+        ``core/plan.py:pack_bucketed``). Built spec -> ``bucketed``; not
+        built -> submit the build, keyed by the spec, and serve the
+        interpreted floor. The first compiled round after degraded ones is
+        the hot-swap."""
+        try:
+            pack, built = self._single_pack(fam, ex, pol, es, graph, params)
+        except Exception:
+            self._contained()
+            res = self._interp_executor(fam).run(graph, pol, es,
+                                                 params=params)
+            return res, "interpreted"
+        qkey = (fam, pack.spec)
+        jobsig = _sig_digest(("cjob", fam, pack.spec))
+        with self.tracer.span("round.lookup"):
+            blocked = self.quarantine.blocks(qkey, self._round)
+        if built and not blocked:
             try:
                 if self._injector is not None:
                     self._injector.on_exec(self._round, "bucketed")
@@ -988,6 +1016,7 @@ class ServeEngine:
                 with self.tracer.span("round.settle"):
                     self.quarantine.clear(qkey)
                     self._note_hotswap(jobsig, fam)
+                self._note_single_lanes(pack)
                 return res, "bucketed"
             except Exception as exc:
                 self.quarantine.record_failure(qkey, self._round, exc)
@@ -999,13 +1028,57 @@ class ServeEngine:
             # This round serves degraded while the build is in flight:
             # remember the sig so its first compiled round counts as a
             # hot-swap (submission itself dedupes inside the service).
-            self._submit_compile_job(fam, ex, pol, graph, jobsig, params)
+            self._submit_spec_job(fam, ex, pack, jobsig, params)
             self._awaiting.add(jobsig)
         res = self._interp_executor(fam).run(graph, pol, es, params=params)
         return res, "interpreted"
 
+    def _single_pack(self, fam: str, ex, pol, es, graph, params):
+        """``(pack, built)``: the wave's cached pack, or one made on the
+        loop in declaration order inside a ``round.single_pack`` span
+        (``plan.schedule`` and ``plan.lower`` nest in it), and whether its
+        spec's executable is built. The span is stamped with the wave's
+        node count, whether it was packed here, and ``spec_ready``."""
+        with self.tracer.span("round.single_pack", nodes=len(graph)) as sp:
+            pack = ex.pack_ready(graph, pol, layout=_LOOP_LAYOUT)
+            fresh = pack is None
+            if fresh:
+                pack = ex.pack_for(graph, pol, es, layout=_LOOP_LAYOUT)
+                self.stats.n_single_fresh_pack += 1
+                self._metrics.counter("serve.single.fresh_pack").inc()
+            built = ex.executable_ready(pack, params)
+            sp.set(fresh=fresh, spec_ready=built)
+        return pack, built
+
+    def _note_single_lanes(self, pack) -> None:
+        real = pack.stats.n_real_lanes
+        padded = pack.spec.n_aux_lanes - real
+        self.stats.n_single_lanes_real += real
+        self.stats.n_single_lanes_padded += padded
+        self._metrics.counter("serve.single.lanes_real").inc(real)
+        self._metrics.counter("serve.single.lanes_padded").inc(padded)
+
+    def _note_single_floor(self) -> None:
+        self.stats.n_single_floor += 1
+        self._metrics.counter("serve.single.floor").inc()
+
+    def _submit_spec_job(self, fam: str, ex, pack, jobsig: str,
+                         params: Any, kind: str = "bucketed") -> bool:
+        """Queue the background build of ``pack``'s bucket spec (the pack
+        is already made; the job is the XLA build alone)."""
+        if self._compiler is None or self._compiler.in_flight(jobsig):
+            return False
+
+        def build(job, span_args, abort):
+            job.qkey = (fam, pack.spec)
+            _, _, dt = ex.build_executable(pack, params, span_args=span_args,
+                                           abort_check=abort)
+            return dt
+
+        return self._compiler.submit(jobsig, build, family=fam, kind=kind)
+
     def _lookup(self, fam: str, ex, pol, graph, params) -> tuple:
-        """The async tiers' dispatch-side probes, in one ``round.lookup``
+        """The async lm tier's dispatch-side probes, in one ``round.lookup``
         span: ``(jobsig, pack, blocked, ready)`` — the build job's digest,
         the cached pack (or ``None``), whether its bucket is quarantined,
         and whether its executable is built and may run now."""
@@ -1157,25 +1230,43 @@ class ServeEngine:
         warm-start descriptor set (persisted next to the XLA cache by the
         launcher; see ``launch/jaxcache.py``). Only lm feed rounds are
         recorded: their topology is the padded entry count alone, so one
-        integer rebuilds the graph and the compile job. Single-shot
-        topologies are request-shaped and not reconstructible from a
-        summary — they warm through the persistent XLA cache instead."""
+        integer rebuilds the graph and the compile job. A single-shot wave
+        is request-shaped and has no such summary; :meth:`prewarm` builds
+        single-shot specs from representative wave graphs instead."""
         return {"version": 1,
                 "families": {"lm": {"counts": sorted(self._seen_lm_counts)}}}
 
     def prewarm(self, warmset: dict | None) -> int:
-        """Pre-submit compile jobs for previously seen bucket signatures
-        (a ``warmset()`` payload or a checkpoint's in-flight descriptors).
-        Returns the number of jobs submitted; no-op without the async
-        service."""
+        """Pre-submit compile jobs for bucket signatures: lm feed rounds by
+        padded entry count (a ``warmset()`` payload or a checkpoint's
+        in-flight descriptors, ``families["lm"]["counts"]``), and
+        single-shot families from representative wave graphs
+        (``families[fam]["graphs"]``), each packed here on the host as the
+        loop packs a fresh wave, and built by its spec. Returns the number
+        of jobs submitted; no-op without the async service."""
         if self._compiler is None or not warmset:
             return 0
-        counts = (warmset.get("families", {})
-                  .get("lm", {}).get("counts", []))
+        fams = warmset.get("families", {})
         n = 0
-        for c in counts:
+        for c in fams.get("lm", {}).get("counts", []):
             n += self._prewarm_lm(int(c))
+        for fam, desc in fams.items():
+            if fam == "lm" or self.n_shards > 1:
+                continue
+            for g in desc.get("graphs", []):
+                n += self._prewarm_single(fam, g)
         return n
+
+    def _prewarm_single(self, fam: str, graph) -> int:
+        ex = self._executor(fam)
+        pol = self.policy_for(fam)
+        pack = ex.pack_for(graph, pol, self._exec_stats[fam],
+                           layout=_LOOP_LAYOUT)
+        if ex.executable_ready(pack, None):
+            return 0
+        jobsig = _sig_digest(("cjob", fam, pack.spec))
+        return int(self._submit_spec_job(fam, ex, pack, jobsig, None,
+                                         kind="warm"))
 
     def _prewarm_lm(self, count: int) -> int:
         if count < 1:
@@ -1881,14 +1972,21 @@ class ServeEngine:
         graph, out_ids = merge_request_graphs(reqs)
         try:
             res, tier = self._exec_graph(fam, graph)
+            # One gather and one read of every request's outputs.
+            ys = np.asarray(res.field("y", [i for ids in out_ids
+                                            for i in ids]))
         except Exception:
             self._contained()
             return self._isolate_single_shot(fam, reqs)
         self._note_tier(tier)
+        if tier == "interpreted":
+            self._note_single_floor()
         now = time.perf_counter()
         st = self._shard_stats[0]
+        off = 0
         for req, ids in zip(reqs, out_ids):
-            req.result = np.asarray(res.field("y", ids))
+            req.result = ys[off:off + len(ids)]
+            off += len(ids)
             req.t_first = now
             st.outputs_out += len(ids)
             self._finish(req, now, st)
@@ -1904,6 +2002,7 @@ class ServeEngine:
         pol = self.policy_for(fam)
         es = self._exec_stats[fam]
         self._note_tier("interpreted")
+        self._note_single_floor()
         for req in reqs:
             try:
                 graph, out_ids = merge_request_graphs([req])
@@ -2035,6 +2134,11 @@ class ServeEngine:
             # Mirrors the per-site st.outputs_out accounting (one row of
             # stacked logits per requested output node).
             self._metrics.counter("serve.outputs_out").inc(len(req.result))
+        if req.family != "lm":
+            # The ledger keeps every request served; a single-shot one
+            # keeps its result and lets its graph go, so a stream of fresh
+            # trees does not pile up nodes for every collection to walk.
+            req.graph = None
         self._metrics.histogram("serve.latency_s").observe(now - req.t_admit)
         self._metrics.histogram("serve.ttft_s").observe(
             req.t_first - req.t_admit)
