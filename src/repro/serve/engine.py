@@ -52,6 +52,7 @@ fault injector the whole ladder is tested under.
 
 from __future__ import annotations
 
+import gc
 import os
 import time
 from dataclasses import dataclass, field, replace
@@ -463,6 +464,7 @@ class ServeEngine:
         self._retired_shard_stats: list[ServeStats] = []
         self._base: dict[str, float] = {}
         self._run_t0: float | None = None
+        self._heap_frozen = False
 
     # -- observability accessors ---------------------------------------------
 
@@ -624,6 +626,29 @@ class ServeEngine:
                               compile_s=round(job.compile_s, 6),
                               round=self._round)
 
+    def _freeze_warm_heap(self) -> None:
+        """Move the warm heap into the permanent generation, once: at the
+        first round boundary where the compile service has landed a
+        bucket program and has no build pending or in flight. What is
+        alive then (JAX, the modules, policies, caches, executables) lives
+        as long as the process, and a generation-2 collection no longer
+        traverses it; objects a round allocates are collected as before.
+        ``close()`` gives the permanent generation back (DESIGN.md §6)."""
+        c = self._compiler
+        if (self._heap_frozen or c is None or not c.stats["landed"]
+                or c.pending_count()):
+            return
+        with self.tracer.span("serve.gc_freeze") as sp:
+            t0 = time.perf_counter()
+            gc.collect()
+            collect_ms = (time.perf_counter() - t0) * 1e3
+            gc.freeze()
+            frozen = gc.get_freeze_count()
+            sp.set(frozen=frozen, collect_ms=collect_ms)
+        self._heap_frozen = True
+        self._metrics.counter("serve.gc.freezes").inc()
+        self._metrics.gauge("serve.gc.frozen_objects").set(frozen)
+
     def _data_mesh(self):
         """The shared 1-D data mesh, built lazily (first executor) so an
         unsharded engine never touches jax device state."""
@@ -717,15 +742,20 @@ class ServeEngine:
         return self.stats
 
     def close(self) -> None:
-        """Tear down background machinery (the compile worker pool).
-        Idempotent; an engine without the async service is a no-op."""
+        """Tear down background machinery (the compile worker pool) and
+        unfreeze the heap this engine froze. Idempotent; an engine without
+        the async service is a no-op."""
         if self._compiler is not None:
             self._compiler.shutdown()
+        if self._heap_frozen:
+            gc.unfreeze()
+            self._heap_frozen = False
 
     def step(self) -> None:
         """One scheduler round: admit, build wave graphs, execute, feed back."""
         with self.tracer.span("serve.poll_compiles"):
             self._poll_compiles()
+        self._freeze_warm_heap()
         if self._injector is not None:
             # Elastic-mesh fault hooks fire at the round boundary, before
             # any of this round's work: a lost replica resizes the mesh (its
